@@ -62,6 +62,8 @@ def main() -> None:
                     help="write a repro.obs JSONL trace of the benchmarked "
                          "runs (summarize with tools/trace_report.py)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.filters)
     if args.metrics_out:
         from repro import obs as obs_mod

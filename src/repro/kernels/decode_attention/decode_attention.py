@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 DEFAULT_BLOCK_KV = 512
 _NEG = -1e30
@@ -126,7 +125,7 @@ def decode_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
                           kv_heads=kvh),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * kvh, g, d), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens, starts, qt, kt, vt)

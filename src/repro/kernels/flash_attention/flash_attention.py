@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 512
@@ -146,7 +145,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)

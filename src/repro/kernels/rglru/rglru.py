@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 _CLIP = 60.0
 
@@ -89,7 +88,7 @@ def rglru_scan(log_a: jax.Array, b: jax.Array, *, chunk: int = 16,
             jax.ShapeDtypeStruct((bsz, 1, w), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_final_ref,
                  state_ref, *, chunk: int):
@@ -127,7 +125,7 @@ def wkv6_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
             jax.ShapeDtypeStruct((b * h, n, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, lwt, ut)

@@ -14,12 +14,8 @@ import numpy as np
 
 
 def _axis_types_kwargs(n_axes: int) -> dict:
-    """`axis_types` only exists on newer jax; older versions (<=0.4.x) treat
-    every axis as auto-sharded already, so omit the kwarg there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """Every mesh axis is auto-sharded."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,13 +39,8 @@ def make_host_mesh(model_parallel: int = 1):
 
 
 def activate(mesh):
-    """Context manager entering `mesh`: `jax.set_mesh` on new jax, the Mesh
-    object's own context on older versions (NamedSharding-based jit works
-    under either)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Context manager entering `mesh` (`jax.set_mesh`)."""
+    return jax.set_mesh(mesh)
 
 
 def describe(mesh) -> str:

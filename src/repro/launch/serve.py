@@ -11,9 +11,11 @@ Modes:
                    the vectorized pull_many hook)
   --mode validate  event-driven serving of N requests at the found optimal
                    vs. the three default corners (paper Results 2)
-  --mode engine    Camel drives the *real* JAX engine (smoke model) —
-                   the arm's batch/frequency change actual batched
-                   inference calls (CPU demo of the deployment loop)
+  --mode engine    Camel drives the *real* JAX engine — the arm's
+                   batch/frequency change actual batched inference
+                   calls; --preset smoke (default, the CPU demo model)
+                   or --preset published (the arch's published widths,
+                   sized for an accelerator)
   --mode tpu       Camel on the TPU v5e roofline-derived landscape
                    (DESIGN.md SS3 adaptation; per --arch)
   --mode fleet     batched Camel over a --fleet-size device fleet behind
@@ -83,7 +85,9 @@ import math
 from repro import obs as obs_mod
 from repro.core import baselines, controller, cost, priors
 from repro.faults import parse_faults, wrap_env, wrap_sensor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.platform import make_env, make_space
+from repro.platform.registry import ENGINE_PRESETS
 from repro.serving import energy as energy_mod
 from repro.serving import simulator as sim_mod
 from repro.serving.requests import ArrivalProcess
@@ -157,7 +161,8 @@ def validate_mode(model: str, n_requests: int, alpha: float, seed: int,
 def engine_mode(arch: str, rounds: int, alpha: float, seed: int,
                 sensor: str = "simulated",
                 decode_impl: str = "fused",
-                scheduler: str = "static", faults=None) -> dict:
+                scheduler: str = "static", faults=None,
+                preset: str = "smoke") -> dict:
     """`sensor` selects the per-pull power source (`repro.obs.make_sensor`
     spec): every engine pull is metered through it.  The default
     "simulated" sensor reads the same analytical board model the
@@ -166,11 +171,14 @@ def engine_mode(arch: str, rounds: int, alpha: float, seed: int,
     generate) or "loop" (per-token reference).  `scheduler` picks the
     serving discipline per pull: "static" (one fixed batch) or
     "continuous" (slot-level admission over Poisson arrivals with
-    ragged output lengths — the batch arm becomes max concurrency)."""
+    ragged output lengths — the batch arm becomes max concurrency).
+    `preset` picks the model: "smoke" (reduced, CPU-sized) or
+    "published" (the architecture's published widths)."""
     name = f"engine/{arch}"
-    env = make_env(name, seed=seed, prompt_len=16, max_new_tokens=8,
-                   sensor=sensor, decode_impl=decode_impl,
-                   scheduler=scheduler, faults=faults)
+    env = make_env(name, preset=preset, seed=seed, prompt_len=16,
+                   max_new_tokens=8, sensor=sensor,
+                   decode_impl=decode_impl, scheduler=scheduler,
+                   faults=faults)
     space = make_space(name)
     cm = cost.CostModel(alpha=alpha)
     e0, l0 = env.pull(space.values(space.corner()), 0)
@@ -178,7 +186,10 @@ def engine_mode(arch: str, rounds: int, alpha: float, seed: int,
     policy = baselines.make_policy("camel", prior_mu=1.0, prior_sigma=0.1)
     ctrl = controller.Controller(space, policy, cm, seed=seed)
     res = ctrl.run(env, rounds)
-    return res.summary()
+    out = res.summary()
+    out["preset"] = preset
+    out["n_pulls"] = len(res.records)
+    return out
 
 
 def tpu_mode(arch: str, rounds: int, alpha: float, seed: int) -> dict:
@@ -331,6 +342,9 @@ def main() -> None:
                     choices=["static", "continuous"],
                     help="engine mode serving discipline: static batches "
                          "or continuous (slot-level) batching")
+    ap.add_argument("--preset", default="smoke", choices=ENGINE_PRESETS,
+                    help="engine mode model: the reduced smoke config or "
+                         "the architecture at its published widths")
     ap.add_argument("--decode-impl", default="fused",
                     choices=["fused", "loop"],
                     help="engine mode decode path: fused (jitted "
@@ -351,6 +365,7 @@ def main() -> None:
                          "(see docs/RESILIENCE.md); empty or 'none' "
                          "disables injection")
     args = ap.parse_args()
+    enable_compile_cache()
 
     plan = parse_faults(args.faults) if args.faults else None
     if plan is not None and plan.is_zero:
@@ -373,7 +388,8 @@ def main() -> None:
             return engine_mode(args.arch, args.rounds, args.alpha,
                                args.seed, sensor=args.sensor,
                                decode_impl=args.decode_impl,
-                               scheduler=args.scheduler, faults=plan)
+                               scheduler=args.scheduler, faults=plan,
+                               preset=args.preset)
         if args.mode == "fleet":
             return fleet_mode(args.model, args.rounds, args.alpha,
                               args.seed, args.fleet_size, k=args.k,

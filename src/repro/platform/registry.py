@@ -7,7 +7,9 @@ Names follow ``<platform>/<model>/<scenario>``:
     tpu-v5e/qwen2-1.5b/landscape     roofline-derived TPU decode landscape
     tpu-v5e/qwen2-1.5b/elastic       + mesh-slice width third knob
     engine/smollm-360m               real InferenceEngine (scenario "live"
-                                     implied; "engine/<arch>/live" also ok)
+                                     implied; "engine/<arch>/live" also ok;
+                                     preset="published" builds the
+                                     published widths, default "smoke")
 
 plus the composite fleet form ``fleet/<n>x<platform>/<model>/<scenario>``
 (e.g. ``fleet/4xjetson/llama3.2-1b/landscape``): N devices of the named
@@ -311,9 +313,15 @@ def _tpu_elastic(model: str, *, model_shards: int = 16, **kw):
     return simulator.TPUElasticEnv(chip, served, **kw)
 
 
+#: Engine model presets: the reduced same-family model the CPU tests and
+#: docs use, or the architecture at its published widths.
+ENGINE_PRESETS = ("smoke", "published")
+
+
 @register_env("engine", "live", space=paper_arm_space,
               models=_config_archs)
-def _engine_live(arch: str, *, seed: int = 0, max_batch: int = 28,
+def _engine_live(arch: str, *, preset: str = "smoke", seed: int = 0,
+                 max_batch: int = 28,
                  max_seq_len: int = 128, prompt_len: int = 16,
                  max_new_tokens: int = 8, arrival_rate: float = 1.0,
                  sensor=None, sample_hz: float = 20.0,
@@ -326,13 +334,20 @@ def _engine_live(arch: str, *, seed: int = 0, max_batch: int = 28,
     from repro.models.registry import bundle_for
     from repro.serving import energy
     from repro.serving.engine import EngineEnvironment, InferenceEngine
+    get_cfg = {"smoke": configs_mod.get_smoke,
+               "published": configs_mod.get}.get(preset)
+    if get_cfg is None:
+        raise ValueError(f"preset must be one of {ENGINE_PRESETS}, "
+                         f"got {preset!r}")
     try:
-        cfg = configs_mod.get_smoke(arch)
+        cfg = get_cfg(arch)
     except ModuleNotFoundError:
         raise KeyError(f"unknown engine model {arch!r}; "
                        f"available: {sorted(configs_mod.ALIASES)}") from None
     bundle = bundle_for(cfg)
-    params = bundle.init_params(jax.random.PRNGKey(seed))
+    # Under jit the f32 init transients fuse into the bf16 result instead
+    # of being materialized op by op (GBs at published widths).
+    params = jax.jit(bundle.init_params)(jax.random.PRNGKey(seed))
     engine = InferenceEngine(bundle, params, max_batch=max_batch,
                              max_seq_len=max_seq_len,
                              decode_impl=decode_impl,

@@ -1,9 +1,14 @@
 """Batched JAX inference engine: prefill + fused greedy decode with KV cache.
 
 This is the real-model backend behind the Camel controller (the simulator
-estimates (E, L); this engine produces them by actually running a model —
-on TPU with wall-clock+power integration, on CPU for the examples/tests
-with simulated energy from the analytical board model).
+estimates (E, L); this engine produces them by actually running a model
+on whatever device JAX uses — the CPU for the examples/tests, a TPU for
+`chip_smoke.py` and ``serve.py --preset published``).  Latency is
+measured wall-clock.  Energy is not measured on a TPU: there is no TPU
+power sensor, so `EngineEnvironment` reads a `repro.obs` sensor (Jetson
+rails, NVML) where one exists and otherwise the analytical Jetson board
+model, with time scaled by that board's DVFS factor — modelled joules,
+never a chip measurement.
 
 Hot-path design (what makes the measured (E, L) reflect hardware, not
 Python dispatch):

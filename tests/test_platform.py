@@ -210,6 +210,11 @@ def test_arm_to_env_to_observation_round_trip(name, knob):
         assert (e, l) == (obs.energy, obs.latency)
 
 
+def test_engine_preset_is_validated():
+    with pytest.raises(ValueError, match="preset"):
+        make_env("engine/smollm-360m", preset="tiny")
+
+
 def test_engine_round_trip():
     """arm -> make_env("engine/...") -> Observation through the real
     InferenceEngine (reduced smoke model on CPU)."""
