@@ -10,11 +10,14 @@ The observability subsystem behind the `Platform.power` contract:
   trapezoidal integration, `measure()` context manager returning
   joules / avg watts / peak watts.
 * `metrics` — counters, gauges, histograms in a `MetricsRegistry`.
-* `tracing` — span/event emitter with a JSONL exporter and the
-  process-wide observation session: `observing(path)` opens a session,
-  instrumented seams call `emit(...)` (a no-op when no session is open,
-  so default runs stay bit-identical), and closing appends the metrics
-  snapshot to the same file.
+* `tracing` — spans and events with a buffered JSONL exporter and the
+  process-wide observation session: `observing(path)` opens a session;
+  `span(name, acc=..., **attrs)` marks a phase on the profiler's host
+  plane, adds its time to a per-call `PhaseTimes` and records a row with
+  its start, end and parent while a session is open; point events go
+  through `emit(...)` (a no-op when no session is open, so default runs
+  stay bit-identical); closing writes the rows and the metrics snapshot
+  to the same file.
 
 Import-light by design (stdlib only at import time): the controller,
 platform, and serving layers all emit through this package, so it must
@@ -29,8 +32,9 @@ from repro.obs.sensors import (FallbackSensor, NVMLSensor, PowerSensor,
                                SensorUnavailable, SimulatedSensor,
                                SysfsRailsSensor, autodetect_sensor,
                                make_sensor)
-from repro.obs.tracing import (ObsSession, active, emit, observing,
-                               session, set_session)
+from repro.obs.tracing import (ObsSession, PhaseTimes, active, emit,
+                               observing, record_span, session,
+                               set_session, span)
 
 __all__ = [
     "EnergyMeter", "Measurement",
@@ -38,5 +42,6 @@ __all__ = [
     "FallbackSensor", "NVMLSensor", "PowerSensor", "RecordingSensor",
     "ReplaySensor", "SensorUnavailable", "SimulatedSensor",
     "SysfsRailsSensor", "autodetect_sensor", "make_sensor",
-    "ObsSession", "active", "emit", "observing", "session", "set_session",
+    "ObsSession", "PhaseTimes", "active", "emit", "observing",
+    "record_span", "session", "set_session", "span",
 ]

@@ -64,7 +64,6 @@ around a persistent slot pool sharing one global KV clock:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax
@@ -106,7 +105,15 @@ class ContinuousStats(EngineStats):
     `sim_s` is the simulation-clock duration of the run (wall time scaled
     by `time_scale`, or `step_time_s` units in deterministic mode) —
     goodput is `n_requests / sim_s`.  `records` carries the per-request
-    accounting (admit/finish times, queue wait, tokens, joules)."""
+    accounting (admit/finish times, queue wait, tokens, joules).
+
+    `phase_s` / `phase_n` hold the seconds and counts of the call's
+    `engine.*` spans by name; `chunks` counts decode chunks.  A slot
+    still vacant when a chunk starts counts its steps as
+    `empty_slot_steps_blocked` when an arrived request waits that the KV
+    clock cannot admit, else as `empty_slot_steps_drain`; with the live
+    slot-steps (`mean_occupancy × decode_steps`) they sum to
+    `n_slots × decode_steps`."""
 
     sim_s: float = 0.0
     decode_steps: int = 0
@@ -116,6 +123,13 @@ class ContinuousStats(EngineStats):
     mean_occupancy: float = 0.0
     mean_queue_wait_s: float = 0.0
     records: List[RequestRecord] = dataclasses.field(default_factory=list)
+    # Span time by name (`engine.*` spans: seconds and counts) and the
+    # scheduler's counters, incremented at the same boundaries.
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    phase_n: Dict[str, int] = dataclasses.field(default_factory=dict)
+    chunks: int = 0
+    empty_slot_steps_blocked: int = 0
+    empty_slot_steps_drain: int = 0
 
     @property
     def goodput_rps(self) -> float:
@@ -149,12 +163,8 @@ class InferenceEngine:
         self.decode_impl = decode_impl
         self.prompt_bucket = prompt_bucket
 
-        self._prefill = jax.jit(
-            lambda p, toks, cache, mask: bundle.prefill(p, toks, cache,
-                                                        attn_mask=mask))
-        self._decode = jax.jit(
-            lambda p, tok, cache, pos, mask: bundle.decode_step(
-                p, tok, cache, pos, attn_mask=mask))
+        self._prefill = jax.jit(self._prefill_fn)
+        self._decode = jax.jit(self._decode_fn)
         self._fused_decode = jax.jit(self._fused_decode_fn,
                                      static_argnums=(5,))
         self._fused_continuous = jax.jit(self._fused_continuous_fn,
@@ -165,6 +175,19 @@ class InferenceEngine:
         # entries stay all-zero and a batch-arm sweep allocates each
         # shape once.
         self._cache_pool: Dict[int, object] = {}
+
+    # -- single calls --------------------------------------------------------
+    # Named functions, not lambdas: a program's name in the profiler's
+    # trace (`jit__prefill_fn`) is its function's name.
+
+    def _prefill_fn(self, params, toks, cache, mask):
+        """Batched prefill of left-padded prompts at offset 0."""
+        return self.bundle.prefill(params, toks, cache, attn_mask=mask)
+
+    def _decode_fn(self, params, tok, cache, pos, mask):
+        """One decode step of the whole batch (the `loop` path)."""
+        return self.bundle.decode_step(params, tok, cache, pos,
+                                       attn_mask=mask)
 
     # -- fused decode ------------------------------------------------------
 
@@ -342,11 +365,12 @@ class InferenceEngine:
         b = toks.shape[0]
         cache = self._cache_for(b)
 
-        t0 = time.monotonic()
-        logits, cache = self._prefill(self.params, jnp.asarray(toks), cache,
-                                      jnp.asarray(mask))
-        logits.block_until_ready()
-        t_prefill = time.monotonic() - t0
+        with obslog.span("engine.prefill", batch=b,
+                         prompt_len=prompt_len) as sp:
+            logits, cache = self._prefill(self.params, jnp.asarray(toks),
+                                          cache, jnp.asarray(mask))
+            logits.block_until_ready()
+        t_prefill = sp.t1 - sp.t0
 
         # Decode-time pad mask over global positions: prompt pads stay
         # invalid, every decode-written slot (>= prompt_len) is valid.
@@ -354,34 +378,29 @@ class InferenceEngine:
         dec_mask[:, :prompt_len] = mask
 
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        t0 = time.monotonic()
-        if self.decode_impl == "fused":
-            out_dev = self._fused_decode(
-                self.params, tok, cache, jnp.asarray(dec_mask),
-                jnp.asarray(prompt_len, jnp.int32), max_new_tokens)
-            out = np.asarray(out_dev)       # the one host sync
-        else:
-            dmask = jnp.asarray(dec_mask)
-            out = np.zeros((b, max_new_tokens), np.int32)
-            for i in range(max_new_tokens):
-                out[:, i] = np.asarray(tok)
-                logits, cache = self._decode(self.params, tok, cache,
-                                             jnp.asarray(prompt_len + i,
-                                                         jnp.int32), dmask)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            tok.block_until_ready()
-        t_decode = time.monotonic() - t0
+        with obslog.span("engine.decode", batch=b,
+                         tokens=b * max_new_tokens,
+                         decode_impl=self.decode_impl) as sp:
+            if self.decode_impl == "fused":
+                out_dev = self._fused_decode(
+                    self.params, tok, cache, jnp.asarray(dec_mask),
+                    jnp.asarray(prompt_len, jnp.int32), max_new_tokens)
+                out = np.asarray(out_dev)       # the one host sync
+            else:
+                dmask = jnp.asarray(dec_mask)
+                out = np.zeros((b, max_new_tokens), np.int32)
+                for i in range(max_new_tokens):
+                    out[:, i] = np.asarray(tok)
+                    logits, cache = self._decode(
+                        self.params, tok, cache,
+                        jnp.asarray(prompt_len + i, jnp.int32), dmask)
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok.block_until_ready()
+        t_decode = sp.t1 - sp.t0
 
         st = EngineStats(prefill_s=t_prefill, decode_s=t_decode,
                          tokens_out=b * max_new_tokens,
                          decode_impl=self.decode_impl)
-        if obslog.active():
-            obslog.emit("engine.prefill", dur_s=t_prefill, batch=b,
-                        prompt_len=prompt_len)
-            obslog.emit("engine.decode", dur_s=t_decode, batch=b,
-                        tokens=st.tokens_out,
-                        decode_impl=self.decode_impl,
-                        tokens_per_s=st.tokens_per_s or None)
         return out, st
 
     # -- continuous generation ---------------------------------------------
@@ -444,12 +463,32 @@ class InferenceEngine:
         prefill_s = decode_s = 0.0
         decode_steps = 0
         prefill_calls = 0
+        chunks = blocked = drain = 0
+        phases = obslog.PhaseTimes()
         outputs: Dict[int, np.ndarray] = {}
+        t_call = obslog.CLOCK()
 
         def tick(wall_dt: float, units: int) -> None:
             nonlocal sim
             sim += (step_time_s * units if step_time_s is not None
                     else wall_dt * time_scale)
+
+        def retired(rec: RequestRecord, now: float) -> None:
+            """A retired request: its wall finish, output and span.  The
+            span starts when the request was due: its `arrival_s`, or its
+            admission if earlier (the engine's clock skips idle time)."""
+            rec.finish_wall_s = now - t_call
+            outputs[rec.rid] = np.asarray(rec.tokens, np.int32)
+            if obslog.active():
+                due = min(rec.arrival_s, rec.admit_wall_s)
+                first = rec.first_token_wall_s
+                obslog.record_span(
+                    "engine.request", t_call + due, now, rid=rec.rid,
+                    slot=rec.slot, tokens=rec.n_tokens,
+                    prompt_len=rec.prompt_len,
+                    queue_wait_s=rec.admit_wall_s - due,
+                    ttft_s=None if first is None else first - due,
+                    cancelled=rec.cancelled)
 
         # Per-slot device/host state between chunks.  Vacant slots carry
         # finished=True, remaining=0 and an all-True mask row (an
@@ -461,105 +500,111 @@ class InferenceEngine:
         remaining = np.zeros((b,), np.int32)
 
         while len(queue) or sched.any_live():
-            # Deadline processing (request cancellation, repro.faults):
-            # expired pending requests are abandoned before admission;
-            # live slots past their deadline retire mid-generate with
-            # the tokens emitted so far and free for refill.  Deadlines
-            # are only checked between chunks, so cancellation latency
-            # is bounded by one scheduler iteration (admission prefills
-            # plus a chunk of decode).
-            for req in queue.expired(sim):
-                queue.pop(req)
-                rec = sched.abandon(req, sim)
-                outputs[req.rid] = np.zeros((0,), np.int32)
-                if obslog.active():
-                    obslog.emit("fault.request", rid=req.rid,
-                                action="abandon",
-                                deadline_s=req.deadline_s,
-                                queue_wait_s=rec.queue_wait_s)
-            for slot in sched.due_cancellations(sim):
-                rec = sched.cancel(slot, sim)
-                outputs[rec.rid] = np.asarray(rec.tokens, np.int32)
-                finished[slot] = True
-                remaining[slot] = 0
-                if obslog.active():
-                    obslog.emit("fault.request", rid=rec.rid,
-                                action="cancel", slot=slot,
-                                tokens=rec.n_tokens)
-                    obslog.emit("engine.request", dur_s=rec.latency_s,
-                                rid=rec.rid, slot=rec.slot,
-                                tokens=rec.n_tokens,
-                                prompt_len=rec.prompt_len,
-                                queue_wait_s=rec.queue_wait_s,
-                                admit_s=rec.admit_s,
-                                finish_s=rec.finish_s, cancelled=True)
-            if not sched.any_live():
-                arrived = queue.arrived(sim)
-                if not arrived:
-                    sim = queue.next_arrival()   # idle: jump to next arrival
-                    continue
-                # Reseed: fresh left-padded batch at clock zero (same path
-                # as static generate — self._prefill at offset 0).
-                group = sched.seed_group(arrived)
-                plen = max(self._bucket_len(len(r.prompt)) for r in group)
-                toks = np.full((b, plen), self.pad_id, np.int32)
-                mask = np.zeros((b, plen), bool)
-                mask[len(group):, :] = True      # dummy rows: defined attn
-                for i, r in enumerate(group):
-                    toks[i, plen - len(r.prompt):] = r.prompt
-                    mask[i, plen - len(r.prompt):] = True
-                t0 = time.monotonic()
-                logits, cache = self._prefill(self.params,
-                                              jnp.asarray(toks),
-                                              self._cache_for(b),
-                                              jnp.asarray(mask))
-                logits.block_until_ready()
-                dt = time.monotonic() - t0
-                prefill_s += dt
-                prefill_calls += 1
-                tick(dt, 1)
-                for r in group:
-                    queue.pop(r)
-                sched.seed(group, plen, sim)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                valid = np.ones((b, self.max_seq_len), bool)
-                valid[:, :plen] = mask
-                finished = np.ones((b,), bool)
-                finished[:len(group)] = False
-                remaining = np.zeros((b,), np.int32)
-                for i, r in enumerate(group):
-                    remaining[i] = r.max_new_tokens
+            with obslog.span("engine.bookkeep", acc=phases):
+                # Deadline processing (request cancellation,
+                # repro.faults): expired pending requests are abandoned
+                # before admission; live slots past their deadline retire
+                # mid-generate with the tokens emitted so far and free for
+                # refill.  Deadlines are only checked between chunks, so
+                # cancellation latency is bounded by one scheduler
+                # iteration (admission prefills plus a chunk of decode).
+                for req in queue.expired(sim):
+                    queue.pop(req)
+                    rec = sched.abandon(req, sim)
+                    rec.finish_wall_s = obslog.CLOCK() - t_call
+                    outputs[req.rid] = np.zeros((0,), np.int32)
+                    if obslog.active():
+                        obslog.emit("fault.request", rid=req.rid,
+                                    action="abandon",
+                                    deadline_s=req.deadline_s,
+                                    queue_wait_s=rec.queue_wait_s)
+                for slot in sched.due_cancellations(sim):
+                    rec = sched.cancel(slot, sim)
+                    finished[slot] = True
+                    remaining[slot] = 0
+                    if obslog.active():
+                        obslog.emit("fault.request", rid=rec.rid,
+                                    action="cancel", slot=slot,
+                                    tokens=rec.n_tokens)
+                    retired(rec, obslog.CLOCK())
+                group = None
+                if not sched.any_live():
+                    arrived = queue.arrived(sim)
+                    if not arrived:
+                        sim = queue.next_arrival()  # idle: jump to arrival
+                        continue
+                    # Reseed: fresh left-padded batch at clock zero (same
+                    # path as static generate — self._prefill at offset 0).
+                    group = sched.seed_group(arrived)
+                    plen = max(self._bucket_len(len(r.prompt))
+                               for r in group)
+                    toks = np.full((b, plen), self.pad_id, np.int32)
+                    mask = np.zeros((b, plen), bool)
+                    mask[len(group):, :] = True  # dummy rows: defined attn
+                    for i, r in enumerate(group):
+                        toks[i, plen - len(r.prompt):] = r.prompt
+                        mask[i, plen - len(r.prompt):] = True
+            if group is not None:
+                with obslog.span("engine.reseed", acc=phases,
+                                 rows=len(group), bucket=plen) as sp:
+                    logits, cache = self._prefill(self.params,
+                                                  jnp.asarray(toks),
+                                                  self._cache_for(b),
+                                                  jnp.asarray(mask))
+                    logits.block_until_ready()
+                with obslog.span("engine.bookkeep", acc=phases):
+                    dt = sp.t1 - sp.t0
+                    prefill_s += dt
+                    prefill_calls += 1
+                    tick(dt, 1)
+                    for r in group:
+                        queue.pop(r)
+                    sched.seed(group, plen, sim)
+                    for slot in range(len(group)):
+                        sched.record_at(slot).admit_wall_s = sp.t0 - t_call
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    valid = np.ones((b, self.max_seq_len), bool)
+                    valid[:, :plen] = mask
+                    finished = np.ones((b,), bool)
+                    finished[:len(group)] = False
+                    remaining = np.zeros((b,), np.int32)
+                    for i, r in enumerate(group):
+                        remaining[i] = r.max_new_tokens
                 # Run the admit loop before decoding: a request that
                 # arrived during the seed prefill may already be
                 # admissible into a vacant slot, and the fused loop
                 # early-exits (steps=0) if it sees it pending instead.
                 continue
-            else:
-                # Refill free slots from the arrived, admissible queue.
-                while sched.free_slots():
+
+            # Refill free slots from the arrived, admissible queue.
+            while sched.free_slots():
+                with obslog.span("engine.bookkeep", acc=phases):
                     cand = next((r for r in queue.arrived(sim)
                                  if sched.can_admit(r)), None)
-                    if cand is None:
-                        break
-                    lb = self._bucket_len(len(cand.prompt))
-                    offset = sched.pos - lb
-                    toks1 = np.full((1, lb), self.pad_id, np.int32)
-                    mask1 = np.zeros((1, lb), bool)
-                    toks1[0, lb - len(cand.prompt):] = cand.prompt
-                    mask1[0, lb - len(cand.prompt):] = True
-                    t0 = time.monotonic()
-                    slot_guess = sched.free_slots()[0]
+                    if cand is not None:
+                        lb = self._bucket_len(len(cand.prompt))
+                        offset = sched.pos - lb
+                        toks1 = np.full((1, lb), self.pad_id, np.int32)
+                        mask1 = np.zeros((1, lb), bool)
+                        toks1[0, lb - len(cand.prompt):] = cand.prompt
+                        mask1[0, lb - len(cand.prompt):] = True
+                        slot_guess = sched.free_slots()[0]
+                if cand is None:
+                    break
+                with obslog.span("engine.admit", acc=phases, rid=cand.rid,
+                                 bucket=lb, slot=slot_guess) as sp:
                     tok1, cache = self._admit(
                         self.params, jnp.asarray(toks1), jnp.asarray(mask1),
                         cache, jnp.asarray(slot_guess, jnp.int32),
                         jnp.asarray(offset, jnp.int32))
                     tok1.block_until_ready()
-                    dt = time.monotonic() - t0
+                    dt = obslog.CLOCK() - sp.t0
                     prefill_s += dt
                     prefill_calls += 1
                     tick(dt, 1)
                     slot = sched.admit(cand, sim)
                     assert slot == slot_guess
+                    sched.record_at(slot).admit_wall_s = sp.t0 - t_call
                     queue.pop(cand)
                     tok = tok.at[slot].set(tok1)
                     row = np.zeros((self.max_seq_len,), bool)
@@ -570,48 +615,60 @@ class InferenceEngine:
 
             # One chunk of fused decode.  A live slot always has
             # remaining <= max_seq_len - pos (admission geometry), so
-            # steps_cap >= 1 and the loop makes progress.
-            live = sched.live_slots()
-            steps_cap = min(chunk, self.max_seq_len - sched.pos)
-            pending = sum(1 for r in queue.arrived(sim)
-                          if sched.can_admit(r))
-            t0 = time.monotonic()
-            steps_d, tok, cache, out_d, fin_d, em_d = self._fused_continuous(
-                self.params, tok, cache, jnp.asarray(valid),
-                jnp.asarray(sched.pos, jnp.int32), jnp.asarray(finished),
-                jnp.asarray(remaining), eos,
-                jnp.asarray(steps_cap, jnp.int32),
-                jnp.asarray(pending, jnp.int32), chunk)
-            steps = int(steps_d)                 # the per-chunk host sync
-            out = np.asarray(out_d)
-            fin_new = np.array(fin_d)            # copy: mutated on admit
-            em = np.asarray(em_d)
-            dt = time.monotonic() - t0
-            decode_s += dt
-            decode_steps += steps
-            tick(dt, steps)
-            if steps == 0:
-                raise RuntimeError(
-                    "continuous decode made no progress (scheduler "
-                    "invariant violated)")
-            for slot in live:
-                if em[slot]:
-                    sched.note_emitted(slot, out[slot, :em[slot]])
-            sched.advance(steps, len(live))
-            finished = fin_new
-            remaining = remaining - em
-            for slot in live:
-                if fin_new[slot]:
-                    rec = sched.retire(slot, sim)
-                    outputs[rec.rid] = np.asarray(rec.tokens, np.int32)
-                    if obslog.active():
-                        obslog.emit("engine.request", dur_s=rec.latency_s,
-                                    rid=rec.rid, slot=rec.slot,
-                                    tokens=rec.n_tokens,
-                                    prompt_len=rec.prompt_len,
-                                    queue_wait_s=rec.queue_wait_s,
-                                    admit_s=rec.admit_s,
-                                    finish_s=rec.finish_s)
+            # steps_cap >= 1 and the loop makes progress.  A slot still
+            # vacant here waits on the KV clock if a request has arrived
+            # (every admissible one was admitted above), else on the
+            # queue's drain.
+            with obslog.span("engine.bookkeep", acc=phases):
+                live = sched.live_slots()
+                steps_cap = min(chunk, self.max_seq_len - sched.pos)
+                arrived = queue.arrived(sim)
+                pending = sum(1 for r in arrived if sched.can_admit(r))
+                vacant = b - len(live)
+            with obslog.span("engine.chunk.upload", acc=phases,
+                             live=len(live)) as up:
+                steps_d, tok, cache, out_d, fin_d, em_d = \
+                    self._fused_continuous(
+                        self.params, tok, cache, jnp.asarray(valid),
+                        jnp.asarray(sched.pos, jnp.int32),
+                        jnp.asarray(finished), jnp.asarray(remaining), eos,
+                        jnp.asarray(steps_cap, jnp.int32),
+                        jnp.asarray(pending, jnp.int32), chunk)
+            # The chunk's three spans tile the interval `decode_s` times.
+            with obslog.span("engine.chunk.wait", acc=phases,
+                             start=up.t1) as wt:
+                steps = int(steps_d)             # the per-chunk host sync
+            with obslog.span("engine.chunk.fetch", acc=phases, start=wt.t1,
+                             steps=steps) as fe:
+                out = np.asarray(out_d)
+                fin_new = np.array(fin_d)        # copy: mutated on admit
+                em = np.asarray(em_d)
+            with obslog.span("engine.bookkeep", acc=phases):
+                dt = fe.t1 - up.t0
+                decode_s += dt
+                decode_steps += steps
+                chunks += 1
+                tick(dt, steps)
+                if steps == 0:
+                    raise RuntimeError(
+                        "continuous decode made no progress (scheduler "
+                        "invariant violated)")
+                if arrived:
+                    blocked += vacant * steps
+                else:
+                    drain += vacant * steps
+                for slot in live:
+                    if em[slot]:
+                        rec = sched.record_at(slot)
+                        if rec.first_token_wall_s is None:
+                            rec.first_token_wall_s = fe.t1 - t_call
+                        sched.note_emitted(slot, out[slot, :em[slot]])
+                sched.advance(steps, len(live))
+                finished = fin_new
+                remaining = remaining - em
+                for slot in live:
+                    if fin_new[slot]:
+                        retired(sched.retire(slot, sim), fe.t1)
 
         recs = sched.records
         st = ContinuousStats(
@@ -624,13 +681,9 @@ class InferenceEngine:
             mean_queue_wait_s=(float(np.mean([r.queue_wait_s
                                               for r in recs]))
                                if recs else 0.0),
-            records=recs)
-        if obslog.active():
-            obslog.emit("engine.prefill", dur_s=prefill_s, batch=b,
-                        prompt_len=-1, calls=prefill_calls)
-            obslog.emit("engine.decode", dur_s=decode_s, batch=b,
-                        tokens=st.tokens_out, decode_impl="fused",
-                        tokens_per_s=st.tokens_per_s or None)
+            records=recs, phase_s=phases.s, phase_n=phases.n,
+            chunks=chunks, empty_slot_steps_blocked=blocked,
+            empty_slot_steps_drain=drain)
         return outputs, st
 
 

@@ -59,7 +59,14 @@ class EngineRequest:
 
 @dataclasses.dataclass
 class RequestRecord:
-    """Per-request accounting, finalized at retire time."""
+    """Per-request accounting, finalized at retire time.
+
+    `arrival_s`, `admit_s` (after the request's prefill) and `finish_s`
+    are on the engine's simulation clock.  The `*_wall_s` stamps are on
+    the span clock (`repro.obs.tracing.CLOCK`), in seconds since the
+    serving call started: the start of the request's admission (its seed
+    batch or single-row prefill), the host's receipt of its first token,
+    and its finish; None where the request never got that far."""
 
     rid: int
     arrival_s: float
@@ -71,6 +78,9 @@ class RequestRecord:
     joules: float = 0.0
     cancelled: bool = False
     tokens: List[int] = dataclasses.field(default_factory=list)
+    admit_wall_s: Optional[float] = None
+    first_token_wall_s: Optional[float] = None
+    finish_wall_s: Optional[float] = None
 
     @property
     def queue_wait_s(self) -> float:
@@ -182,6 +192,13 @@ class SlotScheduler:
 
     def rid_at(self, slot: int) -> Optional[int]:
         return self._occupant[slot]
+
+    def record_at(self, slot: int) -> RequestRecord:
+        """The open record of the request live in `slot`."""
+        rid = self._occupant[slot]
+        if rid is None:
+            raise RuntimeError(f"no request live in slot {slot}")
+        return self._open[rid]
 
     # -- seed / admit / retire --------------------------------------------
 

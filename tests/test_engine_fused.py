@@ -310,3 +310,165 @@ def test_engine_env_continuous_reports_goodput():
     assert 0 < md["mean_occupancy"] <= 4
     assert obs.energy > 0 and obs.latency > 0
     assert obs.queue_wait == md["mean_queue_wait_s"]
+
+
+# -- spans and scheduler counters of the serving loop -----------------------
+
+SPANS = {"engine.reseed": {"rows", "bucket"},
+         "engine.admit": {"rid", "bucket", "slot"},
+         "engine.chunk.upload": {"live"},
+         "engine.chunk.wait": set(),
+         "engine.chunk.fetch": {"steps"},
+         "engine.bookkeep": set()}
+
+
+def _blocking_mix(cfg):
+    """Three slots, 160 positions, bucket 16.  Request 2 (bucket 64, 96
+    new tokens) fits behind the KV clock only at position 64, which the
+    32-step chunks step over, so slots stand vacant while it waits; it is
+    served alone after a reseed, while the queue is drained."""
+    rng = np.random.default_rng(3)
+    spec = [(5, 120), (5, 10), (50, 96), (7, 30), (9, 20)]
+    return [EngineRequest(rid=i, prompt=rng.integers(
+                1, cfg.vocab_size, size=p).astype(np.int32),
+                max_new_tokens=m) for i, (p, m) in enumerate(spec)]
+
+
+@pytest.fixture(scope="module")
+def served():
+    """The same serving call with the observation session closed, then
+    open: ({rid: tokens}, stats) of each and the session's span rows."""
+    import io
+    import json
+
+    from repro import obs
+
+    eng, cfg = _engine("smollm-360m", max_batch=3, max_seq_len=160)
+    reqs = _blocking_mix(cfg)
+    kw = dict(n_slots=3, chunk=32)
+    eng.generate_continuous(reqs, **kw)                # compile
+    out_off, st_off = eng.generate_continuous(reqs, **kw)
+    sink = io.StringIO()
+    with obs.observing(sink):
+        out_on, st_on = eng.generate_continuous(reqs, **kw)
+    rows = [json.loads(line) for line in sink.getvalue().splitlines()]
+    return {"engine": eng, "requests": reqs, "kw": kw, "off": st_off,
+            "on": st_on, "out_off": out_off, "out_on": out_on,
+            "spans": [r for r in rows if r["kind"] == "span"]}
+
+
+def _named(rows, name):
+    return [r for r in rows if r["name"] == name]
+
+
+def test_continuous_tokens_same_with_session_open_or_closed(served):
+    assert served["out_on"].keys() == served["out_off"].keys()
+    for rid, toks in served["out_off"].items():
+        np.testing.assert_array_equal(served["out_on"][rid], toks)
+    assert served["on"].decode_steps == served["off"].decode_steps
+    assert served["on"].chunks == served["off"].chunks
+
+
+def test_continuous_chunk_spans_sum_to_decode_time(served):
+    """upload + wait + fetch of a chunk cover its share of `decode_s`
+    (which times the same interval from outside), per chunk and in
+    total; the fetch spans' `steps` sum to `decode_steps`."""
+    rows = served["spans"]
+    up, wait, fetch = (_named(rows, "engine.chunk." + n)
+                       for n in ("upload", "wait", "fetch"))
+    assert len(up) == len(wait) == len(fetch) == served["on"].chunks > 0
+    for u, w, f in zip(up, wait, fetch):
+        share = f["ts"] - u["start"]
+        # the three spans tile the chunk: each starts where the last ended
+        assert w["start"] == pytest.approx(u["ts"], abs=1e-8)
+        assert f["start"] == pytest.approx(w["ts"], abs=1e-8)
+        assert u["dur_s"] + w["dur_s"] + f["dur_s"] == pytest.approx(
+            share, rel=0.01)
+    total = sum(f["ts"] - u["start"] for u, f in zip(up, fetch))
+    assert total == pytest.approx(served["on"].decode_s, rel=1e-6)
+    for st in (served["on"], served["off"]):
+        ph = st.phase_s
+        assert sum(ph["engine.chunk." + n] for n in (
+            "upload", "wait", "fetch")) == pytest.approx(st.decode_s,
+                                                         rel=0.01)
+        assert st.phase_n["engine.chunk.fetch"] == st.chunks
+    assert sum(f["attrs"]["steps"] for f in fetch) == \
+        served["on"].decode_steps
+    assert all(u["attrs"]["live"] >= 1 for u in up)
+
+
+def test_continuous_prefill_spans_count_prefill_calls(served):
+    rows, st = served["spans"], served["on"]
+    admits, reseeds = (_named(rows, "engine.admit"),
+                       _named(rows, "engine.reseed"))
+    assert len(admits) >= 1 and len(reseeds) == 2
+    assert len(admits) + len(reseeds) == st.prefill_calls
+    assert st.phase_n["engine.admit"] + st.phase_n["engine.reseed"] == \
+        served["off"].prefill_calls == st.prefill_calls
+    assert {a["rid"] for a in admits} <= {r.rid for r in
+                                          served["requests"]}
+    # the serving loop's spans are its top level: none nests in another
+    loop = [r for r in rows if r["name"] in SPANS]
+    assert all(r["parent"] is None for r in loop)
+
+
+def test_continuous_slot_step_counters_cover_the_pool(served):
+    """Blocked, drained and live slot-steps partition n_slots x steps."""
+    for st in (served["off"], served["on"]):
+        live = st.mean_occupancy * st.decode_steps
+        assert st.empty_slot_steps_blocked > 0
+        assert st.empty_slot_steps_drain > 0
+        assert (st.empty_slot_steps_blocked + st.empty_slot_steps_drain
+                + live) == pytest.approx(3 * st.decode_steps)
+
+
+def test_continuous_request_wall_stamps(served):
+    rows = _named(served["spans"], "engine.request")
+    assert sorted(r["rid"] for r in rows) == list(range(5))
+    by_rid = {r["rid"]: r for r in rows}
+    for rec in served["on"].records:
+        assert 0.0 <= rec.admit_wall_s <= rec.first_token_wall_s \
+            <= rec.finish_wall_s
+        row = by_rid[rec.rid]
+        a = row["attrs"]
+        # every request was due at the call's start
+        assert a["queue_wait_s"] == pytest.approx(rec.admit_wall_s)
+        assert a["ttft_s"] == pytest.approx(rec.first_token_wall_s)
+        assert row["dur_s"] == pytest.approx(rec.finish_wall_s)
+        assert a["tokens"] == rec.n_tokens
+    # request 2 waited for a reseed: admitted after every other first token
+    recs = {r.rid: r for r in served["on"].records}
+    assert recs[2].admit_wall_s > max(recs[i].first_token_wall_s
+                                      for i in (0, 1, 3))
+
+
+def test_continuous_profile_holds_every_span(served, tmp_path):
+    """Under the profiler every `engine.*` span lands on the host plane
+    with its attributes as stats, and no program of the loop is a
+    lambda."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    eng = served["engine"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.generate_continuous(served["requests"], **served["kw"])
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen, names = {}, set()
+    for plane in ProfileData.from_file(path[0]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                names.add(e.name)
+                if e.name.startswith("engine."):
+                    seen.setdefault(e.name, set()).update(
+                        k for k, _ in e.stats)
+    assert set(seen) == set(SPANS)
+    for name, stats in SPANS.items():
+        assert stats <= seen[name], name
+    programs = {n for n in names if n.startswith("PjitFunction(")}
+    assert {"PjitFunction(_prefill_fn)", "PjitFunction(_admit_fn)",
+            "PjitFunction(_fused_continuous_fn)"} <= programs
+    assert "PjitFunction(<lambda>)" not in programs

@@ -478,6 +478,90 @@ def test_observing_writes_events_spans_and_metrics(tmp_path):
     assert metrics["events_total.round"]["value"] == 1
 
 
+def test_span_nesting_records_parent_ids_and_rid():
+    sink = io.StringIO()
+    with obs.observing(sink):
+        with tracing_mod.span("outer", phase=1):
+            with tracing_mod.span("inner", rid=7, slot=2):
+                with tracing_mod.span("leaf"):
+                    pass
+            with tracing_mod.span("sibling"):
+                pass
+        with tracing_mod.span("root2"):
+            pass
+    rows = {r["name"]: r for r in _rows(sink) if r["kind"] == "span"}
+    assert rows["outer"]["parent"] is None
+    assert rows["inner"]["parent"] == rows["outer"]["id"]
+    assert rows["leaf"]["parent"] == rows["inner"]["id"]
+    assert rows["sibling"]["parent"] == rows["outer"]["id"]
+    assert rows["root2"]["parent"] is None
+    assert len({r["id"] for r in rows.values()}) == 5
+    assert rows["inner"]["rid"] == 7 and "rid" not in rows["leaf"]
+    assert rows["inner"]["attrs"] == {"slot": 2}
+    assert rows["outer"]["attrs"] == {"phase": 1}
+    for r in rows.values():
+        assert 0.0 <= r["start"] <= r["ts"]
+        assert r["dur_s"] == pytest.approx(r["ts"] - r["start"], abs=1e-8)
+    # rows are appended at each span's end
+    order = [r["name"] for r in _rows(sink) if r["kind"] == "span"]
+    assert order == ["leaf", "inner", "sibling", "outer", "root2"]
+
+
+def test_span_without_session_records_no_row():
+    acc = tracing_mod.PhaseTimes()
+    assert not tracing_mod.active()
+    with tracing_mod.span("engine.chunk.wait", acc=acc) as sp:
+        pass
+    with tracing_mod.span("engine.chunk.wait", acc=acc, steps=3):
+        pass
+    tracing_mod.record_span("engine.request", 0.0, 1.0, rid=1)
+    assert sp.t1 >= sp.t0 > 0.0
+    assert acc.n == {"engine.chunk.wait": 2}
+    assert acc.s["engine.chunk.wait"] >= sp.t1 - sp.t0
+    sink = io.StringIO()
+    with obs.observing(sink):       # a later session sees none of them
+        pass
+    assert [r for r in _rows(sink) if r["kind"] != "metric"] == []
+
+
+def test_span_start_tiles_consecutive_phases():
+    acc = tracing_mod.PhaseTimes()
+    with tracing_mod.span("a", acc=acc) as a:
+        pass
+    with tracing_mod.span("b", acc=acc, start=a.t1) as b:
+        pass
+    with tracing_mod.span("c", acc=acc, start=b.t1, steps=2) as c:
+        pass
+    assert b.t0 == a.t1 and c.t0 == b.t1
+    assert sum(acc.s.values()) == pytest.approx(c.t1 - a.t0, rel=1e-12)
+    assert c.attrs == {"steps": 2}
+
+
+def test_session_buffers_rows_until_close():
+    sink = io.StringIO()
+    with obs.observing(sink) as sess:
+        obs.emit("round.start", round=0)
+        with tracing_mod.span("engine.bookkeep"):
+            pass
+        t = tracing_mod.CLOCK()
+        tracing_mod.record_span("engine.request", t - 0.5, t, rid=4,
+                                tokens=3)
+        assert sink.getvalue() == ""          # nothing written yet
+    rows = _rows(sink)
+    assert [r["name"] for r in rows if r["kind"] != "metric"] == [
+        "round.start", "engine.bookkeep", "engine.request"]
+    req = rows[2]
+    assert req["rid"] == 4 and req["parent"] is None
+    assert req["dur_s"] == pytest.approx(0.5)
+    assert req["start"] == pytest.approx(t - 0.5 - sess.t0, abs=1e-8)
+    metrics = {r["name"]: r for r in rows if r["kind"] == "metric"}
+    assert metrics["engine_requests_total"]["value"] == 1
+    assert metrics["events_total.engine.bookkeep"]["value"] == 1
+    assert "engine.tokens_per_s" not in metrics
+    sess.close()                               # idempotent
+    assert len(_rows(sink)) == len(rows)
+
+
 def test_controller_run_produces_queryable_trace(tmp_path):
     name = "jetson/llama3.2-1b/landscape"
     space = make_space(name)
@@ -592,3 +676,27 @@ def test_trace_report_renders_per_request_table(tmp_path):
     assert "0.5" in row1              # but the span duration renders
     # metrics derived from the spans (counter + latency histogram)
     assert "engine_requests_total" in text
+
+
+def test_trace_report_request_table_reads_wall_stamps(tmp_path):
+    """`engine.request` spans as the engine records them: rid at the top
+    level, wait and time to first token in the attributes, latency the
+    span's own duration on the span clock."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "tools"))
+    try:
+        import trace_report
+    finally:
+        sys.path.pop(0)
+    path = str(tmp_path / "t.jsonl")
+    with obs.observing(path):
+        t = tracing_mod.CLOCK()
+        tracing_mod.record_span("engine.request", t - 2.0, t, rid=5,
+                                slot=0, tokens=9, prompt_len=3,
+                                queue_wait_s=0.75, ttft_s=1.25,
+                                cancelled=False)
+    text = trace_report.report(path)
+    assert "per-request summary (1 requests)" in text
+    assert "mean ttft 1.25 s" in text
+    row = next(ln for ln in text.splitlines() if ln.strip().startswith("5"))
+    assert row.split() == ["5", "0", "3", "9", "0.75", "1.25", "2"]

@@ -6,7 +6,8 @@ and renders:
 * the per-arm pull summary — pulls, mean energy, latency, EDP, cost,
   mean power, mean staleness (async runs), with the committed arm marked;
 * the per-request summary (continuous-batching runs): request count,
-  queue wait / latency / tokens from ``engine.request`` spans;
+  wait, time to first token, latency (on the span clock) and tokens
+  from ``engine.request`` spans;
 * the fault summary (chaos runs, ``--faults``): injected faults,
   retries/backoff, quarantined workers, sensor degradations, cancelled
   requests — from the ``fault.*`` seams;
@@ -120,21 +121,27 @@ def arm_table(rows: List[dict]) -> List[str]:
 
 def request_table(rows: List[dict], max_rows: int = 32) -> List[str]:
     """Per-request summary from `engine.request` spans (continuous
-    batching).  Missing attributes render as blank cells."""
-    reqs = [dict(r.get("attrs", {}), dur_s=r.get("dur_s"))
+    batching).  Their times are on the span clock, from when the request
+    was due: `wait_s` to its admission, `ttft_s` to its first token on
+    the host, and the span's duration to its finish.  Missing attributes
+    render as blank cells."""
+    reqs = [dict(r.get("attrs", {}), dur_s=r.get("dur_s"),
+                 rid=r.get("rid", r.get("attrs", {}).get("rid")))
             for r in rows if r.get("name") == "engine.request"]
     if not reqs:
         return []
     waits = [a.get("queue_wait_s") for a in reqs]
+    ttfts = [a.get("ttft_s") for a in reqs]
     lats = [a.get("dur_s") for a in reqs]
     toks = [a.get("tokens") for a in reqs]
     lines = ["",
              f"per-request summary ({len(reqs)} requests): "
              f"mean wait {_fmt(_mean(waits), 1).strip()} s, "
+             f"mean ttft {_fmt(_mean(ttfts), 1).strip()} s, "
              f"mean latency {_fmt(_mean(lats), 1).strip()} s, "
              f"mean tokens {_fmt(_mean(toks), 1).strip()}",
              f"{'rid':>6}{'slot':>6}{'prompt':>8}{'tokens':>8}"
-             f"{'wait_s':>10}{'latency_s':>11}"]
+             f"{'wait_s':>10}{'ttft_s':>10}{'latency_s':>11}"]
     shown = sorted(reqs, key=lambda a: (a.get("rid") is None,
                                         a.get("rid") or 0))[:max_rows]
     for a in shown:
@@ -142,6 +149,7 @@ def request_table(rows: List[dict], max_rows: int = 32) -> List[str]:
                      f"{_fmt(a.get('prompt_len'), 8)}"
                      f"{_fmt(a.get('tokens'), 8)}"
                      f"{_fmt(a.get('queue_wait_s'), 10)}"
+                     f"{_fmt(a.get('ttft_s'), 10)}"
                      f"{_fmt(a.get('dur_s'), 11)}")
     if len(reqs) > max_rows:
         lines.append(f"  ... {len(reqs) - max_rows} more")
