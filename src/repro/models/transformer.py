@@ -289,9 +289,9 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Params:
 
 
 def _split_layers(cfg: TransformerConfig, layers: Params,
-                  ) -> Tuple[Params, Params, Array, Array]:
-    """Split stacked layer params into (global_stack, local_stack) plus the
-    index vectors mapping group position -> original layer index."""
+                  ) -> Tuple[Optional[Params], Optional[Params]]:
+    """Split stacked layer params into (global_stack, local_stack), each
+    in original layer order (None for an empty group)."""
     import numpy as np
     # analysis: ignore[R001] trace-time constants from static cfg.is_local, not a device sync
     locals_ = np.asarray(cfg.is_local)
@@ -299,110 +299,59 @@ def _split_layers(cfg: TransformerConfig, layers: Params,
     lidx = np.nonzero(locals_)[0]  # analysis: ignore[R001] same static-cfg constant fold
     g = jax.tree.map(lambda a: a[gidx], layers) if len(gidx) else None
     l = jax.tree.map(lambda a: a[lidx], layers) if len(lidx) else None
-    return g, l, jnp.asarray(gidx), jnp.asarray(lidx)
-
-
-def _group_scan(cfg: TransformerConfig, group_params: Params, cache: Params,
-                x_per_layer_fn, ring: bool):
-    """Scan one layer group, threading x through and collecting caches.
-
-    x_per_layer_fn(lp, cache_slice, x) -> (x, new_cache_slice)
-    """
-
-    def body(x, layer):
-        lp, c = layer
-        x, new_c = x_per_layer_fn(lp, c, x)
-        return x, new_c
-
-    return body
+    return g, l
 
 
 def _interleave(cfg: TransformerConfig, params: Params, x: Array,
                 cache: Params, step_fn) -> Tuple[Array, Params]:
-    """Run global and local groups in original layer order.
+    """Run the layers in their original order, carrying the cache.
 
-    Layer order interleaving matters (activations flow through layers
-    sequentially), so we scan each *group* but must preserve order.  For
-    patterns like gemma2's strict alternation we scan over pattern units
-    instead; the generic fallback here runs groups in order of layer index
-    by scanning a merged representation.
+    `step_fn(lp, stack, i, x, is_local) -> (x, stack)` runs layer `i` of
+    its group (`stack` is that group's whole cache, "local" or "global")
+    and returns the stack with what the layer wrote.  The stacks ride in
+    the scan's carry, never in its inputs or outputs, so a decode step
+    writes one position per layer in place instead of emitting a new
+    cache.
 
-    Implementation: we process layers one scan step at a time over the full
-    depth, selecting the right group slice per step via gather — params for
-    both groups are passed; the flag picks which branch executes.  To keep
-    memory bounded we rely on both branches having identical shapes, which
-    holds because local/global layers share parameter shapes (only cache
-    lengths differ).
+    The scan runs over pattern units (gemma2's (local, global) pair; one
+    layer when every layer is of one kind), unrolling the unit, so
+    activations flow through the layers in order while each group keeps
+    its own cache length.
     """
-    g_params, l_params, gidx, lidx = _split_layers(cfg, params["layers"])
-    new_cache: Params = {}
-    # Scan global group first if pattern is all-global (fast path).
-    if l_params is None:
-        def body(x, layer):
-            lp, c = layer
-            x, nc = step_fn(lp, c, x, False)
-            return x, nc
-        x, nc = jax.lax.scan(body, x, (g_params, cache["global"]))
-        new_cache["global"] = nc
-        return x, new_cache
-    if g_params is None:
-        def body(x, layer):
-            lp, c = layer
-            x, nc = step_fn(lp, c, x, True)
-            return x, nc
-        x, nc = jax.lax.scan(body, x, (l_params, cache["local"]))
-        new_cache["local"] = nc
-        return x, new_cache
-
-    # Mixed pattern: scan over repeating pattern units (e.g. gemma2's
-    # (local, global) pair).  Requires the pattern to tile n_layers.
-    pat = cfg.layer_pattern
-    n_units = cfg.n_layers // len(pat)
-    assert n_units * len(pat) == cfg.n_layers, (
+    g_params, l_params = _split_layers(cfg, params["layers"])
+    if g_params is None or l_params is None:
+        unit = (g_params is None,)
+    else:
+        unit = tuple(p == "local" for p in cfg.layer_pattern)
+    n_units = cfg.n_layers // len(unit)
+    assert n_units * len(unit) == cfg.n_layers, (
         "mixed local/global patterns must tile n_layers exactly")
-    per_unit_local = [p == "local" for p in pat]
-    n_loc_u = sum(per_unit_local)
-    n_glob_u = len(pat) - n_loc_u
+    n_loc_u = sum(unit)
+    n_glob_u = len(unit) - n_loc_u
 
-    # Reshape stacked groups to (units, per-unit, ...).
-    g_u = jax.tree.map(
-        lambda a: a.reshape(n_units, n_glob_u, *a.shape[1:]), g_params)
-    l_u = jax.tree.map(
-        lambda a: a.reshape(n_units, n_loc_u, *a.shape[1:]), l_params)
-    gc_u = jax.tree.map(
-        lambda a: a.reshape(n_units, n_glob_u, *a.shape[1:]),
-        cache["global"])
-    lc_u = jax.tree.map(
-        lambda a: a.reshape(n_units, n_loc_u, *a.shape[1:]), cache["local"])
+    def per_unit(group, n):
+        return None if group is None else jax.tree.map(
+            lambda a: a.reshape(n_units, n, *a.shape[1:]), group)
 
-    def unit_body(x, unit):
-        gu, lu, gcu, lcu = unit
-        ncs_g, ncs_l = [], []
-        gi = li = 0
-        for is_loc in per_unit_local:
-            if is_loc:
-                lp = jax.tree.map(lambda a: a[li], lu)
-                c = jax.tree.map(lambda a: a[li], lcu)
-                x, nc = step_fn(lp, c, x, True)
-                ncs_l.append(nc)
-                li += 1
-            else:
-                lp = jax.tree.map(lambda a: a[gi], gu)
-                c = jax.tree.map(lambda a: a[gi], gcu)
-                x, nc = step_fn(lp, c, x, False)
-                ncs_g.append(nc)
-                gi += 1
-        stack = lambda cs: jax.tree.map(
-            lambda *xs: jnp.stack(xs, axis=0), *cs)
-        return x, (stack(ncs_g) if ncs_g else None,
-                   stack(ncs_l) if ncs_l else None)
+    def unit_body(carry, xs):
+        x, cache = carry
+        u, gu, lu = xs
+        seen = {"global": 0, "local": 0}
+        for is_loc in unit:
+            name, group, n = (("local", lu, n_loc_u) if is_loc
+                              else ("global", gu, n_glob_u))
+            j = seen[name]
+            seen[name] += 1
+            lp = jax.tree.map(lambda a: a[j], group)
+            x, stack = step_fn(lp, cache[name], u * n + j, x, is_loc)
+            cache = {**cache, name: stack}
+        return (x, cache), None
 
-    x, (ncg, ncl) = jax.lax.scan(unit_body, x, (g_u, l_u, gc_u, lc_u))
-    new_cache["global"] = jax.tree.map(
-        lambda a: a.reshape(n_units * n_glob_u, *a.shape[2:]), ncg)
-    new_cache["local"] = jax.tree.map(
-        lambda a: a.reshape(n_units * n_loc_u, *a.shape[2:]), ncl)
-    return x, new_cache
+    (x, cache), _ = jax.lax.scan(
+        unit_body, (x, cache),
+        (jnp.arange(n_units, dtype=jnp.int32), per_unit(g_params, n_glob_u),
+         per_unit(l_params, n_loc_u)))
+    return x, cache
 
 
 def prefill(cfg: TransformerConfig, params: Params, tokens: Array,
@@ -429,14 +378,18 @@ def prefill(cfg: TransformerConfig, params: Params, tokens: Array,
             attn_mask = jnp.concatenate(
                 [jnp.ones((x.shape[0], p), bool), attn_mask], axis=1)
 
-    def step_fn(lp, c, x, is_local: bool):
+    def step_fn(lp, stack, i, x, is_local: bool):
         lspec = dataclasses.replace(
             spec, sliding_window=cfg.sliding_window if is_local else 0)
         h = norm(lp["norm_attn"], x)
+        c = common.stack_layer(stack, i)
         a, nc = common.prefill_into_cache(
             lp["attn"], lspec, h, c,
             ring=is_local and c["k"].shape[1] == cfg.sliding_window,
             pad_mask=attn_mask, pos_offset=pos_offset)
+        stack = jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+            stack, nc)
         if cfg.post_norms:
             a = norm(lp["post_norm_attn"], a)
         x = x + a
@@ -449,7 +402,7 @@ def prefill(cfg: TransformerConfig, params: Params, tokens: Array,
             m = common.mlp(lp["mlp"], h, cfg.act)
         if cfg.post_norms:
             m = norm(lp["post_norm_mlp"], m)
-        return x + m, nc
+        return x + m, stack
 
     x, new_cache = _interleave(cfg, params, x, cache, step_fn)
     x = norm(params["final_norm"], x[:, -1:])
@@ -468,13 +421,14 @@ def decode_step(cfg: TransformerConfig, params: Params, token: Array,
     spec = cfg.attn_spec()
     x = common.embed(params, token[:, None], cfg.embed_scale)
 
-    def step_fn(lp, c, x, is_local: bool):
+    def step_fn(lp, stack, i, x, is_local: bool):
         lspec = dataclasses.replace(
             spec, sliding_window=cfg.sliding_window if is_local else 0)
         h = norm(lp["norm_attn"], x)
-        ring = is_local and c["k"].shape[1] == cfg.sliding_window
-        a, nc = common.cached_attention(lp["attn"], lspec, h, c, pos,
-                                        ring=ring, pad_mask=attn_mask)
+        ring = is_local and stack["k"].shape[2] == cfg.sliding_window
+        a, stack = common.cached_attention(lp["attn"], lspec, h, stack, pos,
+                                           ring=ring, pad_mask=attn_mask,
+                                           layer=i)
         if cfg.post_norms:
             a = norm(lp["post_norm_attn"], a)
         x = x + a
@@ -487,7 +441,7 @@ def decode_step(cfg: TransformerConfig, params: Params, token: Array,
             m = common.mlp(lp["mlp"], h, cfg.act)
         if cfg.post_norms:
             m = norm(lp["post_norm_mlp"], m)
-        return x + m, nc
+        return x + m, stack
 
     x, new_cache = _interleave(cfg, params, x, cache, step_fn)
     x = norm(params["final_norm"], x)

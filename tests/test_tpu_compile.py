@@ -14,6 +14,8 @@ this file loads the TPU library.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -124,3 +126,65 @@ def test_continuous_decode_fits_one_chip(one_chip, qwen2):
     assert total < 0.75 * V5E_HBM_BYTES, (
         f"args {mem.argument_size_in_bytes} + out "
         f"{mem.output_size_in_bytes} + temp {mem.temp_size_in_bytes}")
+
+
+def _computations(hlo: str) -> dict:
+    """Optimised HLO text -> {computation name: its instruction lines};
+    the entry computation is keyed "ENTRY"."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _loop_bodies(comps: dict, comp: str) -> list:
+    """Body computations of the while loops that `comp` runs."""
+    return [m.group(1) for line in comps[comp] if " while(" in line
+            for m in [re.search(r"body=%?([\w.\-]+)", line)] if m]
+
+
+#: `temp_size_in_bytes` of the program below when every decode step
+#: re-emitted the layer slabs through the layer scan's outputs.
+SLAB_SCAN_TEMP_BYTES = 6_157_379_072
+
+
+def test_continuous_decode_updates_cache_in_place(one_chip, qwen2):
+    """The served decode program (qwen2-1.5b, 16 slots x 2048, chunk 16):
+    each step writes one position per layer into the carried cache stacks.
+    The chunk loop's body (the entry's while loop that runs the layer scan)
+    holds no copy and no fresh buffer of a whole stack, and the program's
+    temporaries are at least one stack smaller than with the slab scan."""
+    bundle, params = qwen2
+    b, s, chunk = 16, 2048, 16
+    eng = InferenceEngine(bundle, params, max_batch=b, max_seq_len=s)
+    cache = _on(one_chip, jax.eval_shape(lambda: bundle.init_cache(b, s)))
+    i32 = lambda shape=(): _spec(one_chip, shape, jnp.int32)  # noqa: E731
+    compiled = eng._fused_continuous.lower(
+        params, i32((b,)), cache, _spec(one_chip, (b, s), jnp.bool_),
+        i32(), _spec(one_chip, (b,), jnp.bool_), i32((b,)), i32(), i32(),
+        i32(), chunk).compile()
+
+    stack = cache["global"]["k"]
+    assert stack.dtype == jnp.bfloat16
+    shape = f"bf16[{','.join(map(str, stack.shape))}]"
+    comps = _computations(compiled.as_text())
+    chunk_bodies = [body for body in _loop_bodies(comps, "ENTRY")
+                    if _loop_bodies(comps, body)]
+    assert len(chunk_bodies) == 1, chunk_bodies
+    stack_ops = [line for line in comps[chunk_bodies[0]]
+                 if line.split(" = ", 1)[-1].startswith(shape)]
+    assert stack_ops, f"no {shape} value in the chunk loop's body"
+    moved = [line[:160] for line in stack_ops
+             if re.search(r"\bcopy\(", line) or "AllocateBuffer" in line]
+    assert not moved, moved
+
+    stack_bytes = stack.size * jnp.dtype(stack.dtype).itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= SLAB_SCAN_TEMP_BYTES - stack_bytes, temp
