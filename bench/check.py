@@ -5,20 +5,25 @@ from the seed: the one that reaches deepest into the cache (longest prompt
 plus output), one request of the first seed batch, and then others in a
 seeded order until at least ``MIN_TOKENS`` served tokens are in the sample.
 The reference runs once over each prompt followed by its served tokens
-(`reference.gaps`), and the number compared is the widest gap by which a
-served token's logit lies below the reference's best logit at its
-position.  Every served token is greedy, so a correct server reads a gap
-at rounding level and a wrong token reads a gap of the logits' own scale.
+(`gaps`), and the number compared is the widest gap by which a served
+token's logit lies below the reference's best logit at its position.
+Every served token is greedy, so a correct server reads a gap at rounding
+level and a wrong token reads a gap of the logits' own scale.
+
+All of this is shared by every family; the reference's ``logits`` (and
+its control) is the family's own (`family`: ``reference.logits``).
 """
 
 from __future__ import annotations
 
+import functools
+import json
 from typing import Dict, List, Sequence, Set
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-import reference
 from traffic import rng_for
 
 #: Served tokens the sample holds at least ("some hundreds").
@@ -57,12 +62,35 @@ def sample(requests: Sequence[dict], admit_s: Dict[int, float],
     return picked
 
 
-def widest_gaps(m: dict, params, picked: Sequence[dict],
+@functools.partial(jax.jit, static_argnums=(0, 1, 5))
+def gaps(logits, m_json, params, tokens, targets, control: bool):
+    """Per position, how far the logit of ``targets`` lies below the
+    reference's best (0 where the target is the reference's argmax);
+    ``logits`` is the family's reference, ``m_json`` the whole ``model``
+    section as JSON (hashable, nested groups such as ``rope_scaling``
+    included).
+
+    tokens, targets: [S] int32; positions with ``targets < 0`` read 0.
+    With ``control``, also the same gap of the token the family's control
+    puts first.  Returns ([S] program gaps, [S] control gaps or zeros)."""
+    m = json.loads(m_json)
+    ref = logits(m, params, tokens)
+    best = jnp.max(ref, axis=-1)
+    valid = targets >= 0
+    at = jnp.take_along_axis(ref, jnp.maximum(targets, 0)[:, None], -1)[:, 0]
+    prog = jnp.where(valid, best - at, 0.0)
+    if not control:
+        return prog, jnp.zeros_like(prog)
+    ctl_tok = jnp.argmax(logits(m, params, tokens, control=True), axis=-1)
+    ctl = jnp.take_along_axis(ref, ctl_tok[:, None], -1)[:, 0]
+    return prog, jnp.where(valid, best - ctl, 0.0)
+
+
+def widest_gaps(fam, m: dict, params, picked: Sequence[dict],
                 outputs: Dict[int, np.ndarray], control: bool = False):
     """(program's widest gap, control's widest gap or 0.0, tokens compared)
-    over the requests ``picked``."""
-    items = tuple(sorted((k, v) for k, v in m.items()
-                         if isinstance(v, (bool, int, float, str))))
+    over the requests ``picked``, against family ``fam``'s reference."""
+    m_json = json.dumps(m, sort_keys=True)
     prog = ctl = 0.0
     n = 0
     for r in picked:
@@ -73,8 +101,8 @@ def widest_gaps(m: dict, params, picked: Sequence[dict],
         tokens[:len(seq)] = seq
         targets = np.full(length, -1, np.int32)
         targets[len(prompt) - 1:len(seq)] = served
-        g, c = reference.gaps(items, params, jnp.asarray(tokens),
-                              jnp.asarray(targets), control)
+        g, c = gaps(fam.reference.logits, m_json, params,
+                    jnp.asarray(tokens), jnp.asarray(targets), control)
         prog = max(prog, float(jnp.max(g)))
         ctl = max(ctl, float(jnp.max(c)))
         n += len(served)

@@ -4,11 +4,11 @@
 
 For each seed: build the cell as `run.py` does, serve a window of the
 cell's own traffic, then read, over the same seeded sample of served
-requests, the program's widest gap and the fp8 control's (`reference`:
-the float32 reference with both operands of every matrix product rounded
-to e4m3).  The program's largest reading over the seeds is the limit's
-lower end, the control's smallest its upper end.  Benchmark runs never run
-the control.  Prints one line per seed and, last, one JSON object.
+requests, the program's widest gap and the control's (the family's
+`reference`; for Qwen2 the float32 reference with both operands of every
+matrix product rounded to e4m3).  The program's largest reading over the
+seeds is the limit's lower end, the control's smallest its upper end.
+Benchmark runs never run the control.  Prints one line per seed and, last, one JSON object.
 """
 
 from __future__ import annotations
@@ -29,8 +29,8 @@ def readings(plan: dict, seeds, seconds: float) -> list:
                                        cell["served"])
         del cell["engine"]
         gc.collect()
-        v = run.judge(plan["config"], cell["params"], cell["requests"],
-                      outputs, stats, seed, control=True)
+        v = run.judge(cell["family"], plan["config"], cell["params"],
+                      cell["requests"], outputs, stats, seed, control=True)
         out.append((seed, v["checks"]["widest_gap"]["value"],
                     v["control_gap"], v["compared"]))
         run.log(f"seed {seed}: program {out[-1][1]:.6f}, control "

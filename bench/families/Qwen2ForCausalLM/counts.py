@@ -1,10 +1,15 @@
-"""Operations and minimal HBM bytes of a dense GQA decoder, from its shapes.
+"""Operations and minimal HBM bytes of the Qwen2 family (a dense GQA
+decoder), from its shapes.
+
+Family code (`family`).  The readers (`decode_roofline`, `mfu`) are
+shared: they read only `window_work`'s ``prefill_flops``, ``decode_flops``
+and ``decode_bytes``, through the run's ``work``.
 
 Everything here is computed from the configuration file's ``model`` section
 (Hugging Face key names), never from the program under test, so a change to
 the program cannot change the yardstick.
 
-Conventions, the same for every reader of these counts:
+Conventions, which another family's counts keep for the readers' sake:
 
 * a multiply-add is 2 FLOPs; only matrix products and attention's two
   contractions are counted (norms, RoPE, softmax and SiLU are not);

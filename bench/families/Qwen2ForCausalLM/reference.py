@@ -1,4 +1,8 @@
-"""Plain float32 reference of a Qwen2-style decoder, and its fp8 control.
+"""Plain float32 reference of the Qwen2 family, and its fp8 control.
+
+Family code (`family`): `logits` of one sequence.  What every family
+shares -- the sample of served requests, the gap of each served token
+below the reference's best, and the limit -- is `check`'s.
 
 Written from the published description (Qwen2 technical report,
 arXiv:2407.10671; Hugging Face ``Qwen2ForCausalLM``): token embedding; per
@@ -7,7 +11,7 @@ layer RMSNorm, attention with biased Q/K/V projections, rotary positions
 softmax, residual add, RMSNorm, SwiGLU MLP, residual add; final RMSNorm and
 the output head tied to the embedding.  It imports nothing of the program
 under test and reads only the configuration's ``model`` section and the
-weights that `weights.make` built (layout documented there).
+weights that this family's `weights.make` built (layout documented there).
 
 Every matrix product runs at ``Precision.HIGHEST`` in float32.  The control
 (``control=True``) is the same network computed in fp8 (e4m3): both
@@ -21,12 +25,10 @@ weights at a time, so the reference fits beside the served bf16 weights.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from counts import dims
+from .counts import dims
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -95,23 +97,3 @@ def logits(m: dict, params, tokens, control: bool = False):
     x = _rms(x, params["final_norm"]["scale"], eps)
     return _mm("sd,vd->sv", x, f32(params["embedding"]), control)
 
-
-@functools.partial(jax.jit, static_argnums=(0, 4))
-def gaps(m_items, params, tokens, targets, control: bool):
-    """Per position, how far the logit of ``targets`` lies below the
-    reference's best (0 where the target is the reference's argmax).
-
-    tokens, targets: [S] int32; positions with ``targets < 0`` read 0.
-    With ``control``, also the same gap of the token the fp8 control puts
-    first.  Returns ([S] program gaps, [S] control gaps or zeros)."""
-    m = dict(m_items)
-    ref = logits(m, params, tokens)
-    best = jnp.max(ref, axis=-1)
-    valid = targets >= 0
-    at = jnp.take_along_axis(ref, jnp.maximum(targets, 0)[:, None], -1)[:, 0]
-    prog = jnp.where(valid, best - at, 0.0)
-    if not control:
-        return prog, jnp.zeros_like(prog)
-    ctl_tok = jnp.argmax(logits(m, params, tokens, control=True), axis=-1)
-    ctl = jnp.take_along_axis(ref, ctl_tok[:, None], -1)[:, 0]
-    return prog, jnp.where(valid, best - ctl, 0.0)

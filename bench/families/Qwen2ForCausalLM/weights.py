@@ -1,8 +1,9 @@
-"""Seeded random weights of a dense GQA decoder, made on the device.
+"""Seeded random weights of the Qwen2 family (a dense GQA decoder).
 
-The benchmark makes the weights, in the dtype they are served in (bf16),
-in one jitted call from the run's seed, and hands the same arrays to the
-system under test and to the reference.  Layout (layers stacked on axis 0):
+Family code (`family`): the layout below and its standard deviations.
+The draw itself is shared (`seeded.make`: bf16, on the device, one jitted
+call from the run's seed); the same arrays go to the system under test and
+to the reference.  Layout (layers stacked on axis 0):
 
     embedding [V, D]                     (also the tied output head)
     layers.norm_attn.scale, norm_mlp.scale [L, D]   RMSNorm gain - 1
@@ -13,32 +14,20 @@ system under test and to the reference.  Layout (layers stacked on axis 0):
 
 Norm gains are stored as offsets from 1 (gain = 1 + scale).  Matrices have
 standard deviation 1/sqrt(fan-in), the embedding 0.02, biases and gain
-offsets 0.1, all drawn uniformly (a uniform draw keeps each leaf one fused
-pass of random bits).
+offsets 0.1.
 """
 
 from __future__ import annotations
 
 import math
 
-import jax
-import jax.numpy as jnp
+import seeded
 
-from counts import dims
-from traffic import rng_for
+from .counts import dims
 
 BIAS_STD = 0.1
 GAIN_STD = 0.1
 EMBED_STD = 0.02
-
-
-def jax_key(seed: int) -> jax.Array:
-    return jax.random.PRNGKey(int(rng_for(seed, 0).integers(0, 2 ** 31)))
-
-
-def _uniform(key, shape, std, dtype):
-    a = std * math.sqrt(3.0)
-    return jax.random.uniform(key, shape, jnp.float32, -a, a).astype(dtype)
 
 
 def shapes(m: dict) -> dict:
@@ -67,16 +56,4 @@ def shapes(m: dict) -> dict:
 def make(m: dict, seed: int):
     """All weights of config section ``m`` for ``seed``, on the default
     device, in one jitted call."""
-    spec = shapes(m)
-    leaves, tree = jax.tree.flatten(
-        spec, is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
-        and isinstance(x[0], tuple))
-
-    @jax.jit
-    def build(key):
-        keys = jax.random.split(key, len(leaves))
-        return jax.tree.unflatten(
-            tree, [_uniform(k, shape, std, jnp.bfloat16)
-                   for k, (shape, std) in zip(keys, leaves)])
-
-    return build(jax_key(seed))
+    return seeded.make(shapes(m), seed)

@@ -1,9 +1,9 @@
 """The served decode program's share of its roofline, in percent: the
 least time the chip could take for the window's decode work, the larger
 of its FLOPs over peak FLOP/s and its minimal bytes over peak HBM
-bandwidth (`counts.window_work` of the traced window), over the device
-time of the compiled decode program (`_fused_continuous_fn`) in the
-trace."""
+bandwidth (the family's `counts.window_work` of the traced window), over
+the device time of the compiled decode program (`_fused_continuous_fn`)
+in the trace."""
 
 MODULE = "_fused_continuous_fn"
 

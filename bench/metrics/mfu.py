@@ -1,5 +1,6 @@
-"""Model FLOPs of the window (every prefill and decode token, `counts`)
-over the window's wall seconds times the chip's bf16 peak, in percent."""
+"""Model FLOPs of the window (every prefill and decode token, the
+family's `counts`) over the window's wall seconds times the chip's bf16
+peak, in percent."""
 
 
 def read(ctx):

@@ -7,8 +7,23 @@ names a configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``), and ``BENCHMARK.json`` lists the
 metrics the cell reports.  Each metric is read by ``bench/metrics/<name>.py``
 or, for a name split by cell kind (``decode_step_ms.chat``), by the reader
-of the part before the first dot.  Adding a cell, mix or metric adds files
-and edits none.
+of the part before the first dot.
+
+What depends on the architecture is family code, found through `family`
+by the configuration's ``model["architectures"][0]`` in
+``bench/families/<name>/``: the program's entry (``program``), the seeded
+weights (``weights``), the float32 reference and its control
+(``reference``) and the analytic counts (``counts``).  Everything else is
+shared: this runner, the traffic generator, the seeded draw (`seeded`),
+the sample and gap behind ``correct`` (`check`), the trace reductions and
+the readers.
+
+Adding a cell, mix or metric adds files and edits none.  A configuration
+of a new family adds its four family files; its cells get the existing
+readers by per-layer entries of their own named ``<reader>.<suffix>``
+(``decode_roofline.mla-offline``) with their own ``workloads`` list, and
+by appending the cell's name to the ``workloads`` of the end-to-end
+metrics they report (``tokens_per_s``).
 
 One run: refuse anything but enough TPU chips; build the served engine
 from the configuration with weights made from the seed; warm up every
@@ -111,28 +126,6 @@ def require_chips(ident: dict, chips: int) -> None:
             f"({ident['kind']!r}) and never falls back")
 
 
-def program_config(config: dict):
-    """The program's model configuration for ``config``: the program's own
-    entry for ``arch`` with every size taken from the configuration file."""
-    import dataclasses
-
-    import repro.configs as C
-    from counts import dims
-
-    s = dims(config["model"])
-    base = C.get(config["arch"])
-    cfg = dataclasses.replace(
-        base, n_layers=s["L"], d_model=s["D"], n_heads=s["H"],
-        n_kv_heads=s["KV"], head_dim=s["hd"], d_ff=s["F"], vocab_size=s["V"],
-        rope_theta=config["model"]["rope_theta"])
-    m = config["model"]
-    if (cfg.qkv_bias, cfg.tie_embeddings, cfg.act, cfg.norm) != (
-            True, m["tie_word_embeddings"], m["hidden_act"], "rmsnorm"):
-        raise SystemExit(f"bench: the program's {config['arch']} is not the "
-                         f"architecture {config['name']} describes")
-    return cfg
-
-
 def buckets(mix: dict, bucket: int) -> list:
     lo = -(-mix["prompt"]["min"] // bucket) * bucket
     hi = -(-mix["prompt"]["max"] // bucket) * bucket
@@ -184,22 +177,25 @@ class CompileWatch:
 
 def build(plan: dict, seed: int, seconds: float) -> dict:
     """The served engine with weights made from ``seed``, warmed up for
-    the cell's mix, and the window's requests (``requests`` as the
+    the cell's mix, the configuration's family (`family.load`), and the
+    window's requests (``requests`` as the
     generator made them, ``served`` as the engine takes them)."""
     import jax
 
+    import family
     import traffic
-    import weights
     from repro.models.registry import bundle_for
     from repro.serving.engine import InferenceEngine
 
     config, mix = plan["config"], plan["mix"]
     m, eng = config["model"], config["engine"]
+    fam = family.load(config)
     t_import = time.perf_counter()
-    params = weights.make(m, seed)
+    params = fam.weights.make(m, seed)
     jax.block_until_ready(params)
     t_weights = time.perf_counter()
-    engine = InferenceEngine(bundle_for(program_config(config)), params,
+    engine = InferenceEngine(bundle_for(fam.program.program_config(config)),
+                             params,
                              max_batch=eng["n_slots"],
                              max_seq_len=eng["max_seq_len"],
                              prompt_bucket=eng["prompt_bucket"])
@@ -209,8 +205,8 @@ def build(plan: dict, seed: int, seconds: float) -> dict:
     log(f"set-up: imports {t_import - T0:.3f} s, weights "
         f"{t_weights - t_import:.3f} s, engine + traffic + warm-up "
         f"{t_warm - t_weights:.3f} s")
-    return {"engine": engine, "params": params, "requests": requests,
-            "served": engine_requests(requests)}
+    return {"engine": engine, "family": fam, "params": params,
+            "requests": requests, "served": engine_requests(requests)}
 
 
 def engine_requests(requests: list) -> list:
@@ -241,11 +237,12 @@ def window(engine, eng: dict, served: list):
     return outputs, stats, window_s
 
 
-def judge(config: dict, params, requests: list, outputs: dict, stats,
+def judge(fam, config: dict, params, requests: list, outputs: dict, stats,
           seed: int, control: bool = False) -> dict:
     """The numbers compared for ``correct``, each beside its limit, over a
-    seeded sample of the window's served requests; with ``control`` also
-    the fp8 control's widest gap over the same sample."""
+    seeded sample of the window's served requests, against family
+    ``fam``'s reference; with ``control`` also the fp8 control's widest
+    gap over the same sample."""
     import check
 
     m = config["model"]
@@ -253,8 +250,8 @@ def judge(config: dict, params, requests: list, outputs: dict, stats,
     ok = [r for r in requests if r["rid"] not in bad]
     admit = {rec.rid: rec.admit_s for rec in stats.records}
     gap, ctl, n_cmp = (check.widest_gaps(
-        m, params, check.sample(ok, admit, seed), outputs, control) if ok
-        else (None, None, 0))
+        fam, m, params, check.sample(ok, admit, seed),
+        outputs, control) if ok else (None, None, 0))
     checks = {"widest_gap": {"value": gap,
                              "limit": config["correct"]["widest_gap"]},
               "unserved": {"value": len(bad), "limit": 0}}
@@ -264,13 +261,13 @@ def judge(config: dict, params, requests: list, outputs: dict, stats,
                            for c in checks.values())}
 
 
-def traced_window(engine, plan: dict, seed: int, seconds: float) -> dict:
+def traced_window(engine, fam, plan: dict, seed: int,
+                  seconds: float) -> dict:
     """Serve the first ``TRACE_SECONDS`` of the cell's traffic again, under
     the profiler; the trace's reduction, with the window's work under
-    ``work`` (`counts.window_work`)."""
+    ``work`` (family ``fam``'s ``counts.window_work``)."""
     import jax
 
-    import counts
     import trace_reduce
     import traffic
 
@@ -286,7 +283,7 @@ def traced_window(engine, plan: dict, seed: int, seconds: float) -> dict:
         jax.profiler.stop_trace()
     reduced = trace_reduce.reduce_file(trace_reduce.find_trace(TRACE_DIR),
                                        WINDOW)
-    reduced["work"] = counts.window_work(
+    reduced["work"] = fam.counts.window_work(
         m, [(len(r["prompt"]), len(outputs.get(r["rid"], ())))
             for r in requests], stats.decode_steps)
     return reduced
@@ -296,8 +293,6 @@ def run_cell(plan: dict, seed: int, seconds: float, trace: bool,
              t0: float) -> dict:
     """One run of a cell on the default device; returns the result object."""
     import jax
-
-    import counts
 
     config = plan["config"]
     m, eng = config["model"], config["engine"]
@@ -311,8 +306,9 @@ def run_cell(plan: dict, seed: int, seconds: float, trace: bool,
         f"{stats.prefill_s + stats.decode_s:.3f} s of it (prefill "
         f"{stats.prefill_s:.3f} s in {stats.prefill_calls} calls, decode "
         f"{stats.decode_s:.3f} s in {stats.decode_steps} steps)")
-    reduced = (traced_window(cell["engine"], plan, seed, seconds) if trace
-               else None)
+    fam = cell["family"]
+    reduced = (traced_window(cell["engine"], fam, plan, seed, seconds)
+               if trace else None)
 
     mem = jax.devices()[0].memory_stats() or {}
     device = dict(ident, memory_peak_bytes=mem.get("peak_bytes_in_use"))
@@ -325,12 +321,13 @@ def run_cell(plan: dict, seed: int, seconds: float, trace: bool,
 
     # Correctness, after the windows and with the program's state freed.
     requests = cell["requests"]
-    verdict = judge(config, cell["params"], requests, outputs, stats, seed)
+    verdict = judge(fam, config, cell["params"], requests, outputs, stats,
+                    seed)
 
     ctx = {"stats": stats, "records": stats.records, "n_slots": eng["n_slots"],
            "window_s": window_s, "setup_s": setup_s, "trace": reduced,
            "model": m, "peaks": peaks,
-           "work": counts.window_work(
+           "work": fam.counts.window_work(
                m, [(len(r["prompt"]), len(outputs.get(r["rid"], ())))
                    for r in requests], stats.decode_steps)}
     metrics = {}

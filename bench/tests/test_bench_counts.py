@@ -1,4 +1,5 @@
-"""Analytic counts (`bench/counts.py`) and the peaks table, on the CPU."""
+"""Analytic counts (the family's `counts.py`, through `family.load`) and
+the peaks table, on the CPU."""
 
 import json
 import os
@@ -9,7 +10,7 @@ import pytest
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
 
-import counts  # noqa: E402
+import family  # noqa: E402
 import run  # noqa: E402
 
 
@@ -25,6 +26,7 @@ def test_param_count_matches_program(name):
     import repro.configs as C
 
     config = _model(name)
+    counts = family.load(config).counts
     cfg = C.get(config["arch"])
     m = config["model"]
     s = counts.dims(m)
@@ -38,6 +40,7 @@ def test_param_count_matches_program(name):
                                      ("qwen2.5-3b", 36864)])
 def test_kv_bytes_follow_live_context(name, kv):
     config = _model(name)
+    counts = family.load(config).counts
     m = config["model"]
     assert counts.kv_bytes_per_token(m) == kv
     w = counts.weight_bytes(m)
@@ -50,7 +53,9 @@ def test_kv_bytes_follow_live_context(name, kv):
 
 
 def test_window_work_sums_request_steps():
-    m = _model("qwen2-1.5b")["model"]
+    config = _model("qwen2-1.5b")
+    counts = family.load(config).counts
+    m = config["model"]
     # prompt 10, 4 tokens: decode steps at contexts 11, 12, 13.
     work = counts.window_work(m, [(10, 4)], decode_steps=3)
     assert work["decode_tokens"] == 3

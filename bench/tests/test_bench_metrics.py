@@ -9,7 +9,7 @@ import pytest
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
 
-import counts  # noqa: E402
+import family  # noqa: E402
 import run  # noqa: E402
 from repro.serving.engine import ContinuousStats  # noqa: E402
 from repro.serving.scheduler import RequestRecord  # noqa: E402
@@ -18,7 +18,9 @@ with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
     SPEC = json.load(f)
 NAMES = [m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]]
 with open(os.path.join(BENCH, "configs", "qwen2-1.5b.json")) as f:
-    MODEL = json.load(f)["model"]
+    CONFIG = json.load(f)
+MODEL = CONFIG["model"]
+counts = family.load(CONFIG).counts
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
