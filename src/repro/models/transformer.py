@@ -288,20 +288,6 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Params:
     return cache
 
 
-def _split_layers(cfg: TransformerConfig, layers: Params,
-                  ) -> Tuple[Optional[Params], Optional[Params]]:
-    """Split stacked layer params into (global_stack, local_stack), each
-    in original layer order (None for an empty group)."""
-    import numpy as np
-    # analysis: ignore[R001] trace-time constants from static cfg.is_local, not a device sync
-    locals_ = np.asarray(cfg.is_local)
-    gidx = np.nonzero(~locals_)[0]  # analysis: ignore[R001] same static-cfg constant fold
-    lidx = np.nonzero(locals_)[0]  # analysis: ignore[R001] same static-cfg constant fold
-    g = jax.tree.map(lambda a: a[gidx], layers) if len(gidx) else None
-    l = jax.tree.map(lambda a: a[lidx], layers) if len(lidx) else None
-    return g, l
-
-
 def _interleave(cfg: TransformerConfig, params: Params, x: Array,
                 cache: Params, step_fn) -> Tuple[Array, Params]:
     """Run the layers in their original order, carrying the cache.
@@ -316,41 +302,40 @@ def _interleave(cfg: TransformerConfig, params: Params, x: Array,
     The scan runs over pattern units (gemma2's (local, global) pair; one
     layer when every layer is of one kind), unrolling the unit, so
     activations flow through the layers in order while each group keeps
-    its own cache length.
+    its own cache length.  It reads the stored layer stack as it lies:
+    splitting the leading axis into [n_units, len(unit)] is a bitcast, so
+    no layer weight is copied.
     """
-    g_params, l_params = _split_layers(cfg, params["layers"])
-    if g_params is None or l_params is None:
-        unit = (g_params is None,)
+    locals_ = cfg.is_local
+    if all(locals_) or not any(locals_):
+        unit = locals_[:1]
     else:
         unit = tuple(p == "local" for p in cfg.layer_pattern)
     n_units = cfg.n_layers // len(unit)
     assert n_units * len(unit) == cfg.n_layers, (
         "mixed local/global patterns must tile n_layers exactly")
-    n_loc_u = sum(unit)
-    n_glob_u = len(unit) - n_loc_u
-
-    def per_unit(group, n):
-        return None if group is None else jax.tree.map(
-            lambda a: a.reshape(n_units, n, *a.shape[1:]), group)
+    per_unit = {"local": sum(unit), "global": len(unit) - sum(unit)}
+    layers = jax.tree.map(
+        lambda a: a.reshape(n_units, len(unit), *a.shape[1:]),
+        params["layers"])
 
     def unit_body(carry, xs):
         x, cache = carry
-        u, gu, lu = xs
+        u, unit_layers = xs
         seen = {"global": 0, "local": 0}
-        for is_loc in unit:
-            name, group, n = (("local", lu, n_loc_u) if is_loc
-                              else ("global", gu, n_glob_u))
+        for k, is_loc in enumerate(unit):
+            name = "local" if is_loc else "global"
             j = seen[name]
             seen[name] += 1
-            lp = jax.tree.map(lambda a: a[j], group)
-            x, stack = step_fn(lp, cache[name], u * n + j, x, is_loc)
+            lp = jax.tree.map(lambda a: a[k], unit_layers)
+            x, stack = step_fn(lp, cache[name], u * per_unit[name] + j, x,
+                               is_loc)
             cache = {**cache, name: stack}
         return (x, cache), None
 
     (x, cache), _ = jax.lax.scan(
         unit_body, (x, cache),
-        (jnp.arange(n_units, dtype=jnp.int32), per_unit(g_params, n_glob_u),
-         per_unit(l_params, n_loc_u)))
+        (jnp.arange(n_units, dtype=jnp.int32), layers))
     return x, cache
 
 

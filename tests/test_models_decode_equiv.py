@@ -132,6 +132,46 @@ def test_ring_cache_sliding_window(name, layer_pattern):
                                rtol=3e-3, atol=3e-3)
 
 
+@pytest.mark.parametrize("name,layer_pattern", [
+    pytest.param("qwen2_1p5b", None, id="qwen2_1p5b"),
+    pytest.param("gemma2_27b", None, id="gemma2_27b"),
+    pytest.param("gemma2_27b", ("local", "local", "global"),
+                 id="gemma2_27b-local-local-global"),
+])
+def test_layer_scan_gathers_no_weight_stack(name, layer_pattern):
+    """Prefill and decode scan the stored layer stack as it lies: no
+    `gather` takes a whole layer-weight stack ([n_layers, ...]) as its
+    operand, which would copy every layer's weights on every call."""
+    from repro.analysis.jaxpr_audit import iter_eqns
+
+    cfg = C.get_smoke(name)
+    if layer_pattern is not None:
+        cfg = dataclasses.replace(cfg, layer_pattern=layer_pattern,
+                                  n_layers=2 * len(layer_pattern))
+    b = bundle_for(cfg)
+    params = b.abstract_params()
+    stacks = {(a.shape, a.dtype) for a in jax.tree.leaves(params["layers"])}
+    B, S = 2, 32
+    cache = jax.eval_shape(lambda: b.init_cache(B, S))
+    toks = jax.ShapeDtypeStruct((B, 8), jnp.int32)
+    tok = jax.ShapeDtypeStruct((B,), jnp.int32)
+    pos = jax.ShapeDtypeStruct((), jnp.int32)
+    programs = {
+        "prefill": jax.make_jaxpr(b.prefill)(params, toks, cache),
+        "decode_step": jax.make_jaxpr(b.decode_step)(params, tok, cache,
+                                                     pos),
+    }
+    for program, jaxpr in programs.items():
+        gathers = [e for e in iter_eqns(jaxpr)
+                   if e.primitive.name == "gather"]
+        assert gathers, f"{program}: the embedding lookup is a gather"
+        copied = [(e.invars[0].aval.shape, e.invars[0].aval.dtype)
+                  for e in gathers
+                  if (e.invars[0].aval.shape,
+                      e.invars[0].aval.dtype) in stacks]
+        assert not copied, f"{program} gathers layer stacks {copied}"
+
+
 @pytest.mark.parametrize("name", ["smollm_360m", "qwen2_1p5b",
                                   "gemma2_27b"])
 def test_flash_decode_matches_naive(name):

@@ -150,26 +150,48 @@ def _loop_bodies(comps: dict, comp: str) -> list:
             for m in [re.search(r"body=%?([\w.\-]+)", line)] if m]
 
 
-#: `temp_size_in_bytes` of the program below when every decode step
-#: re-emitted the layer slabs through the layer scan's outputs.
+#: `temp_size_in_bytes` of the served decode program below when every
+#: decode step re-emitted the layer slabs through the layer scan's outputs.
 SLAB_SCAN_TEMP_BYTES = 6_157_379_072
 
+#: `temp_size_in_bytes` of the served decode program and of the admission
+#: program (prompt bucket 256) when every call gathered a copy of each
+#: layer-weight stack before the layer scan, one while loop per stack.
+GATHERED_LAYERS_TEMP_BYTES = {"continuous": 4_953_484_800,
+                              "admit": 4_277_091_840}
 
-def test_continuous_decode_updates_cache_in_place(one_chip, qwen2):
+#: Instructions in each program's layer-scan body with that gather.
+GATHERED_LAYERS_SCAN_OPS = {"continuous": 89, "admit": 97}
+
+
+@pytest.fixture(scope="module")
+def served(one_chip, qwen2):
+    """The served programs of qwen2-1.5b at 16 slots x 2048: the chunked
+    continuous decode (chunk 16) and the admission of one prompt of
+    bucket 256, compiled; and the pool cache's shapes."""
+    bundle, params = qwen2
+    b, s, chunk, bucket = 16, 2048, 16, 256
+    eng = InferenceEngine(bundle, params, max_batch=b, max_seq_len=s)
+    cache = _on(one_chip, jax.eval_shape(lambda: bundle.init_cache(b, s)))
+    i32 = lambda shape=(): _spec(one_chip, shape, jnp.int32)  # noqa: E731
+    continuous = eng._fused_continuous.lower(
+        params, i32((b,)), cache, _spec(one_chip, (b, s), jnp.bool_),
+        i32(), _spec(one_chip, (b,), jnp.bool_), i32((b,)), i32(), i32(),
+        i32(), chunk).compile()
+    admit = eng._admit.lower(
+        params, i32((1, bucket)), _spec(one_chip, (1, bucket), jnp.bool_),
+        cache, i32(), i32()).compile()
+    return {"continuous": continuous, "admit": admit}, cache
+
+
+def test_continuous_decode_updates_cache_in_place(served):
     """The served decode program (qwen2-1.5b, 16 slots x 2048, chunk 16):
     each step writes one position per layer into the carried cache stacks.
     The chunk loop's body (the entry's while loop that runs the layer scan)
     holds no copy and no fresh buffer of a whole stack, and the program's
     temporaries are at least one stack smaller than with the slab scan."""
-    bundle, params = qwen2
-    b, s, chunk = 16, 2048, 16
-    eng = InferenceEngine(bundle, params, max_batch=b, max_seq_len=s)
-    cache = _on(one_chip, jax.eval_shape(lambda: bundle.init_cache(b, s)))
-    i32 = lambda shape=(): _spec(one_chip, shape, jnp.int32)  # noqa: E731
-    compiled = eng._fused_continuous.lower(
-        params, i32((b,)), cache, _spec(one_chip, (b, s), jnp.bool_),
-        i32(), _spec(one_chip, (b,), jnp.bool_), i32((b,)), i32(), i32(),
-        i32(), chunk).compile()
+    programs, cache = served
+    compiled = programs["continuous"]
 
     stack = cache["global"]["k"]
     assert stack.dtype == jnp.bfloat16
@@ -188,3 +210,28 @@ def test_continuous_decode_updates_cache_in_place(one_chip, qwen2):
     stack_bytes = stack.size * jnp.dtype(stack.dtype).itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= SLAB_SCAN_TEMP_BYTES - stack_bytes, temp
+
+
+@pytest.mark.parametrize("program", ["continuous", "admit"])
+def test_layer_scan_reads_weights_in_place(served, qwen2, program):
+    """The served programs scan the stored layer stack as it lies.  The
+    entry computation runs one while loop (the chunk loop, whose body runs
+    the layer scan; for the admission, the layer scan itself), so no loop
+    gathers a copy of a weight stack; the layer scan's body is no larger
+    than with the gather, and the temporaries are at least the layer
+    weights' bytes below what they were with it."""
+    _, params = qwen2
+    compiled = served[0][program]
+    comps = _computations(compiled.as_text())
+    loops = _loop_bodies(comps, "ENTRY")
+    assert len(loops) == 1, f"{len(loops)} while loops in the entry"
+    scan = loops[0]
+    if program == "continuous":
+        (scan,) = _loop_bodies(comps, loops[0])
+    assert len(comps[scan]) <= GATHERED_LAYERS_SCAN_OPS[program], (
+        len(comps[scan]))
+
+    layer_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(params["layers"]))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= GATHERED_LAYERS_TEMP_BYTES[program] - layer_bytes, temp
