@@ -14,9 +14,6 @@ Prints ``name,us_per_call,derived`` CSV rows per module:
     E11 heterogeneity beyond-paper  shared vs device-contextual posterior
                       under persistent per-device speed offsets (same
                       module: benchmarks.fleet_scaling)
-    E12 engine_throughput  decode tokens/s and per-token latency vs
-                      batch, fused fori_loop vs per-token loop (writes
-                      BENCH_engine.json)
     E13 engine_continuous  continuous vs static batching goodput under
                       Poisson arrivals with ragged output lengths, plus
                       EOS early-exit (writes BENCH_continuous.json)
@@ -37,9 +34,8 @@ import traceback
 
 def main() -> None:
     from benchmarks import (ablations, config_search, engine_continuous,
-                            engine_throughput, fleet_scaling, kernels,
-                            landscape, resilience, roofline, sensitivity,
-                            tpu_serving, validation)
+                            fleet_scaling, kernels, landscape, resilience,
+                            roofline, sensitivity, tpu_serving, validation)
 
     modules = [
         ("E1_landscape", landscape),
@@ -51,7 +47,6 @@ def main() -> None:
         ("E8_kernels", kernels),
         ("E9_ablations", ablations),
         ("E10_E11_fleet_scaling", fleet_scaling),
-        ("E12_engine_throughput", engine_throughput),
         ("E13_engine_continuous", engine_continuous),
         ("E14_resilience", resilience),
     ]

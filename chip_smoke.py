@@ -235,6 +235,7 @@ def check_logits(engine) -> None:
     dec_mask[:, :plen] = mask
     dec_mask = jnp.asarray(dec_mask)
     after_prefill = cache
+    decode = jax.jit(bundle.decode_step)
 
     got = [np.asarray(logits[0])]
     seq = list(prompt)
@@ -242,14 +243,14 @@ def check_logits(engine) -> None:
     first = tok
     for i in range(CHECK_STEPS):
         seq.append(int(tok[0]))
-        logits, cache = engine._decode(engine.params, tok, cache,
-                                       jnp.asarray(plen + i, jnp.int32),
-                                       dec_mask)
+        logits, cache = decode(engine.params, tok, cache,
+                               jnp.asarray(plen + i, jnp.int32),
+                               attn_mask=dec_mask)
         got.append(np.asarray(logits[0]))
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     # Control: the first decode step one position late.
-    shifted, _ = engine._decode(engine.params, first, after_prefill,
-                                jnp.asarray(plen + 1, jnp.int32), dec_mask)
+    shifted, _ = decode(engine.params, first, after_prefill,
+                        jnp.asarray(plen + 1, jnp.int32), attn_mask=dec_mask)
 
     ref_bundle = bundle_for(dataclasses.replace(cfg, dtype=jnp.float32))
 

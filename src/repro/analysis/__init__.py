@@ -19,7 +19,7 @@ Two stages:
 
 * **Stage 2 — jaxpr contract audit** (`repro.analysis.jaxpr_audit`):
   traces every registered model family's ``prefill``/``decode_step``
-  and the fused/continuous engine loops on tiny shapes and asserts
+  and the engine's slot-pool decode loop on tiny shapes and asserts
   machine-readable contracts — zero host callbacks, no float64, fp32
   softmax/logit accumulation, per-entry-point primitive-count budgets
   (``analysis_budgets.json``, diffed not just thresholded), and a

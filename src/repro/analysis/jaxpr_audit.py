@@ -22,7 +22,7 @@ contracts on the result:
   shape-relevant axes (prompt buckets, batch arms) and the explicitly
   non-shape-relevant ones (prompt content, raggedness within a bucket,
   round index, continuous-batching occupancy churn) and fails if any
-  jit cache grows on the latter — or if the fused decode retraces on
+  jit cache grows on the latter — or if the decode loop retraces on
   the prompt bucket, whose start position is contractually traced.
 * **A106 — traceability**: the entry point must trace at all; a
   ``.item()`` / ``float()`` planted in a traced body raises a
@@ -30,8 +30,8 @@ contracts on the result:
 
 Entry points: every family in `FAMILIES` (one representative per model
 family, same list the engine differential tests pin) gets
-``prefill`` + ``decode_step``; the engine contributes its fused decode
-loop, the continuous (slot-pool) loop, and the admission prefill.
+``prefill`` + ``decode_step``; the engine contributes its one decode
+loop (the slot-pool loop it serves) and the admission prefill.
 """
 
 from __future__ import annotations
@@ -175,8 +175,8 @@ def default_engine_factory():
 
 def engine_entry_thunks(engine_factory: Optional[Callable] = None,
                         ) -> Dict[str, Callable[[], object]]:
-    """Fused decode loop, continuous (slot-pool) loop, and admission
-    prefill of the serving engine."""
+    """The slot-pool decode loop and the admission prefill of the
+    serving engine."""
     import jax
     import jax.numpy as jnp
 
@@ -190,11 +190,6 @@ def engine_entry_thunks(engine_factory: Optional[Callable] = None,
         mask = jnp.ones((b, s), bool)
         start = jnp.asarray(8, jnp.int32)
         return eng, cache, tok, mask, start
-
-    def fused(_s=_setup):
-        eng, cache, tok, mask, start = _s()
-        return jax.make_jaxpr(eng._fused_decode_fn, static_argnums=(5,))(
-            eng.params, tok, cache, mask, start, 4)
 
     def continuous(_s=_setup):
         eng, cache, tok, mask, start = _s()
@@ -215,8 +210,7 @@ def engine_entry_thunks(engine_factory: Optional[Callable] = None,
             eng.params, toks1, mask1, cache,
             jnp.asarray(0, jnp.int32), jnp.asarray(8, jnp.int32))
 
-    return {"engine/fused_decode": fused,
-            "engine/continuous_decode": continuous,
+    return {"engine/continuous_decode": continuous,
             "engine/admit_prefill": admit}
 
 
@@ -368,12 +362,12 @@ def retrace_audit(engine_factory: Optional[Callable] = None,
             hint="something in the hot path keys a trace on values; "
                  "find the leaked python scalar/shape"))
 
-    # Prompt bucket is shape-relevant for *prefill only*: the fused
-    # decode takes the start position as a traced scalar, so a new
+    # Prompt bucket is shape-relevant for *prefill only*: the decode
+    # loop takes the start position as a traced scalar, so a new
     # bucket must not retrace it.
     eng.generate(prompts([9, 12]), 4)          # bucket 16
     bucket = dict(eng.compile_counts)
-    for key in ("decode_fused", "decode_continuous", "admit"):
+    for key in ("decode_continuous", "admit"):
         if bucket.get(key, 0) != flat.get(key, 0):
             out.append(Finding(
                 rule="A105", path="", line=0, stage="audit",
@@ -395,7 +389,7 @@ def retrace_audit(engine_factory: Optional[Callable] = None,
     # jit (and one cache-pool row).
     eng.generate(prompts([5, 7, 6]), 4)
     batch = dict(eng.compile_counts)
-    for key in ("prefill", "decode_fused"):
+    for key in ("prefill", "decode_continuous"):
         if batch.get(key, 0) > bucket.get(key, 0) + 1:
             out.append(Finding(
                 rule="A105", path="", line=0, stage="audit",

@@ -160,24 +160,21 @@ def validate_mode(model: str, n_requests: int, alpha: float, seed: int,
 
 def engine_mode(arch: str, rounds: int, alpha: float, seed: int,
                 sensor: str = "simulated",
-                decode_impl: str = "fused",
                 scheduler: str = "static", faults=None,
                 preset: str = "smoke") -> dict:
     """`sensor` selects the per-pull power source (`repro.obs.make_sensor`
     spec): every engine pull is metered through it.  The default
     "simulated" sensor reads the same analytical board model the
-    unmetered path evaluates, bit-identically.  `decode_impl` picks the
-    engine's decode path: "fused" (jitted fori_loop, one host sync per
-    generate) or "loop" (per-token reference).  `scheduler` picks the
-    serving discipline per pull: "static" (one fixed batch) or
-    "continuous" (slot-level admission over Poisson arrivals with
-    ragged output lengths — the batch arm becomes max concurrency).
+    unmetered path evaluates, bit-identically.  `scheduler` picks the
+    traffic each pull serves: "static" (one fixed batch) or
+    "continuous" (Poisson arrivals with ragged output lengths — the
+    batch arm becomes max concurrency); both run the engine's one
+    slot-pool decode loop.
     `preset` picks the model: "smoke" (reduced, CPU-sized) or
     "published" (the architecture's published widths)."""
     name = f"engine/{arch}"
     env = make_env(name, preset=preset, seed=seed, prompt_len=16,
-                   max_new_tokens=8, sensor=sensor,
-                   decode_impl=decode_impl, scheduler=scheduler,
+                   max_new_tokens=8, sensor=sensor, scheduler=scheduler,
                    faults=faults)
     space = make_space(name)
     cm = cost.CostModel(alpha=alpha)
@@ -345,11 +342,6 @@ def main() -> None:
     ap.add_argument("--preset", default="smoke", choices=ENGINE_PRESETS,
                     help="engine mode model: the reduced smoke config or "
                          "the architecture at its published widths")
-    ap.add_argument("--decode-impl", default="fused",
-                    choices=["fused", "loop"],
-                    help="engine mode decode path: fused (jitted "
-                         "fori_loop, one host sync per generate) or "
-                         "loop (per-token reference)")
     ap.add_argument("--sensor", default="simulated",
                     help="power source: simulated | sysfs | nvml | "
                          "replay:<path> | record:<path> (engine mode "
@@ -387,7 +379,6 @@ def main() -> None:
         if args.mode == "engine":
             return engine_mode(args.arch, args.rounds, args.alpha,
                                args.seed, sensor=args.sensor,
-                               decode_impl=args.decode_impl,
                                scheduler=args.scheduler, faults=plan,
                                preset=args.preset)
         if args.mode == "fleet":

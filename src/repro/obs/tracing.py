@@ -103,10 +103,6 @@ class ObsSession:
         "dispatch.submit": [("counter", "dispatch_submits_total", None)],
         "dispatch.wave": [("counter", "dispatch_waves_total", None),
                           ("gauge", "dispatch_clock_s", "clock_s")],
-        "engine.prefill": [("counter", "engine_prefills_total", None),
-                           ("histogram", "engine_prefill_s", "dur_s")],
-        "engine.decode": [("counter", "engine_decodes_total", None),
-                          ("histogram", "engine_decode_s", "dur_s")],
         "engine.request": [("counter", "engine_requests_total", None),
                            ("histogram", "engine_request_latency_s",
                             "dur_s"),

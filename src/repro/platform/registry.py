@@ -325,7 +325,7 @@ def _engine_live(arch: str, *, preset: str = "smoke", seed: int = 0,
                  max_seq_len: int = 128, prompt_len: int = 16,
                  max_new_tokens: int = 8, arrival_rate: float = 1.0,
                  sensor=None, sample_hz: float = 20.0,
-                 decode_impl: str = "fused", prompt_bucket: int = 16,
+                 prompt_bucket: int = 16,
                  scheduler: str = "static",
                  requests_per_pull=None, eos_id=None, chunk: int = 16,
                  faults=None):
@@ -350,7 +350,6 @@ def _engine_live(arch: str, *, preset: str = "smoke", seed: int = 0,
     params = jax.jit(bundle.init_params)(jax.random.PRNGKey(seed))
     engine = InferenceEngine(bundle, params, max_batch=max_batch,
                              max_seq_len=max_seq_len,
-                             decode_impl=decode_impl,
                              prompt_bucket=prompt_bucket)
     board = energy.JETSON_AGX_ORIN
     work = energy.ORIN_WORKLOADS["llama3.2-1b"]

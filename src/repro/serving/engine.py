@@ -13,12 +13,13 @@ never a chip measurement.
 Hot-path design (what makes the measured (E, L) reflect hardware, not
 Python dispatch):
 
-* **Fused decode** — the default decode path is one jitted
-  ``lax.fori_loop`` keeping the greedy token, KV cache, and an on-device
-  output buffer (``dynamic_update_slice``) inside a single compiled
-  computation: one host sync per `generate` call instead of one per
-  token.  The per-token Python loop survives as ``decode_impl="loop"``,
-  the reference the fused path is asserted bit-identical against.
+* **One decode loop** — decoding runs in one jitted ``lax.while_loop``
+  (`_fused_continuous_fn`) keeping the greedy tokens, KV cache, and an
+  on-device output buffer (``dynamic_update_slice``) inside a single
+  compiled computation: one host sync per chunk of steps instead of one
+  per token.  `generate` is `generate_continuous` with every request
+  queued at t = 0 and one chunk as long as the budget, so a static batch
+  costs one seed prefill and one chunk call.
 * **Prompt bucketing** — padded prompt lengths are rounded up to
   ``prompt_bucket`` multiples, so a controller sweep over ragged prompts
   compiles the prefill once per (batch, bucket) instead of once per
@@ -86,7 +87,6 @@ class EngineStats:
     prefill_s: float
     decode_s: float
     tokens_out: int
-    decode_impl: str = "fused"
 
     @property
     def total_s(self) -> float:
@@ -140,18 +140,12 @@ class ContinuousStats(EngineStats):
 class InferenceEngine:
     """Greedy batched generation with jitted prefill + fused decode.
 
-    decode_impl: "fused" (default — one compiled fori_loop per generate)
-    or "loop" (per-token Python loop with a host round-trip per step; the
-    reference implementation).  prompt_bucket: padded prompt lengths are
-    rounded up to this multiple to bound prefill retraces.
+    prompt_bucket: padded prompt lengths are rounded up to this multiple
+    to bound prefill retraces.
     """
 
     def __init__(self, bundle: ModelBundle, params, max_batch: int,
-                 max_seq_len: int, pad_id: int = 0,
-                 decode_impl: str = "fused", prompt_bucket: int = 16):
-        if decode_impl not in ("fused", "loop"):
-            raise ValueError(f"decode_impl must be 'fused' or 'loop', "
-                             f"got {decode_impl!r}")
+                 max_seq_len: int, pad_id: int = 0, prompt_bucket: int = 16):
         if prompt_bucket < 1:
             raise ValueError(f"prompt_bucket must be >= 1, "
                              f"got {prompt_bucket}")
@@ -160,13 +154,9 @@ class InferenceEngine:
         self.max_batch = max_batch
         self.max_seq_len = max_seq_len
         self.pad_id = pad_id
-        self.decode_impl = decode_impl
         self.prompt_bucket = prompt_bucket
 
         self._prefill = jax.jit(self._prefill_fn)
-        self._decode = jax.jit(self._decode_fn)
-        self._fused_decode = jax.jit(self._fused_decode_fn,
-                                     static_argnums=(5,))
         self._fused_continuous = jax.jit(self._fused_continuous_fn,
                                          static_argnums=(10,))
         self._admit = jax.jit(self._admit_fn)
@@ -183,36 +173,6 @@ class InferenceEngine:
     def _prefill_fn(self, params, toks, cache, mask):
         """Batched prefill of left-padded prompts at offset 0."""
         return self.bundle.prefill(params, toks, cache, attn_mask=mask)
-
-    def _decode_fn(self, params, tok, cache, pos, mask):
-        """One decode step of the whole batch (the `loop` path)."""
-        return self.bundle.decode_step(params, tok, cache, pos,
-                                       attn_mask=mask)
-
-    # -- fused decode ------------------------------------------------------
-
-    def _fused_decode_fn(self, params, tok, cache, mask, start_pos, steps):
-        """One compiled computation for the whole decode phase.
-
-        tok: [B] greedy token from prefill; mask: [B, max_seq_len] pad
-        validity over global positions; start_pos: traced scalar (bucketed
-        prompt length — changing it does NOT retrace); steps: static.
-        Returns the [B, steps] token buffer (single device->host transfer
-        at the caller).
-        """
-        b = tok.shape[0]
-        out = jnp.zeros((b, steps), jnp.int32)
-
-        def body(i, carry):
-            tok, cache, out = carry
-            out = jax.lax.dynamic_update_slice(out, tok[:, None], (0, i))
-            logits, cache = self.bundle.decode_step(
-                params, tok, cache, start_pos + i, attn_mask=mask)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return tok, cache, out
-
-        _, _, out = jax.lax.fori_loop(0, steps, body, (tok, cache, out))
-        return out
 
     # -- continuous decode -------------------------------------------------
 
@@ -233,7 +193,7 @@ class InferenceEngine:
         (the buffer width) is static, so occupancy churn never retraces.
 
         With no EOS hits and no vacancies this body performs exactly the
-        ops of `_fused_decode_fn`'s fori body in the same order — the
+        ops of a per-token `decode_step` loop in the same order — the
         differential identity test pins that bit-for-bit.
         """
         b = tok.shape[0]
@@ -328,8 +288,6 @@ class InferenceEngine:
         pool size) — the retrace regression tests assert these stay flat
         across repeated pulls at the same (batch, bucket)."""
         return {"prefill": self._prefill._cache_size(),
-                "decode_loop": self._decode._cache_size(),
-                "decode_fused": self._fused_decode._cache_size(),
                 "decode_continuous": self._fused_continuous._cache_size(),
                 "admit": self._admit._cache_size(),
                 "cache_pool": len(self._cache_pool)}
@@ -359,49 +317,18 @@ class InferenceEngine:
     def generate(self, prompts: List[np.ndarray], max_new_tokens: int,
                  ) -> Tuple[np.ndarray, EngineStats]:
         """Greedy-decode `max_new_tokens` for each prompt.
-        Returns (tokens [B, max_new_tokens], stats)."""
+        Returns (tokens [B, max_new_tokens], stats).
+
+        The static batch is served by the slot-pool loop: every request is
+        queued at t = 0 on `len(prompts)` slots with one chunk as long as
+        the budget — one seed prefill, one decode call, one host sync."""
         self._validate(prompts, max_new_tokens)
-        toks, mask, prompt_len = self._pad_batch(prompts)
-        b = toks.shape[0]
-        cache = self._cache_for(b)
-
-        with obslog.span("engine.prefill", batch=b,
-                         prompt_len=prompt_len) as sp:
-            logits, cache = self._prefill(self.params, jnp.asarray(toks),
-                                          cache, jnp.asarray(mask))
-            logits.block_until_ready()
-        t_prefill = sp.t1 - sp.t0
-
-        # Decode-time pad mask over global positions: prompt pads stay
-        # invalid, every decode-written slot (>= prompt_len) is valid.
-        dec_mask = np.ones((b, self.max_seq_len), bool)
-        dec_mask[:, :prompt_len] = mask
-
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        with obslog.span("engine.decode", batch=b,
-                         tokens=b * max_new_tokens,
-                         decode_impl=self.decode_impl) as sp:
-            if self.decode_impl == "fused":
-                out_dev = self._fused_decode(
-                    self.params, tok, cache, jnp.asarray(dec_mask),
-                    jnp.asarray(prompt_len, jnp.int32), max_new_tokens)
-                out = np.asarray(out_dev)       # the one host sync
-            else:
-                dmask = jnp.asarray(dec_mask)
-                out = np.zeros((b, max_new_tokens), np.int32)
-                for i in range(max_new_tokens):
-                    out[:, i] = np.asarray(tok)
-                    logits, cache = self._decode(
-                        self.params, tok, cache,
-                        jnp.asarray(prompt_len + i, jnp.int32), dmask)
-                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                tok.block_until_ready()
-        t_decode = sp.t1 - sp.t0
-
-        st = EngineStats(prefill_s=t_prefill, decode_s=t_decode,
-                         tokens_out=b * max_new_tokens,
-                         decode_impl=self.decode_impl)
-        return out, st
+        reqs = [EngineRequest(rid=i, prompt=p, max_new_tokens=max_new_tokens)
+                for i, p in enumerate(prompts)]
+        outs, st = self.generate_continuous(reqs, n_slots=len(prompts),
+                                            eos_id=None,
+                                            chunk=max_new_tokens)
+        return np.stack([outs[i] for i in range(len(prompts))]), st
 
     # -- continuous generation ---------------------------------------------
 
@@ -445,9 +372,9 @@ class InferenceEngine:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
         if self.bundle.family == "encdec":
             raise ValueError(
-                "continuous batching is unsupported for the encdec family "
-                "(absolute sinusoidal positions forbid offset admission; "
-                "see models/encdec.py)")
+                "the engine does not serve the encdec family (absolute "
+                "sinusoidal positions forbid offset admission; see "
+                "models/encdec.py)")
         b = n_slots if n_slots is not None else min(self.max_batch,
                                                     len(reqs))
         if not 1 <= b <= self.max_batch:
@@ -674,7 +601,7 @@ class InferenceEngine:
         st = ContinuousStats(
             prefill_s=prefill_s, decode_s=decode_s,
             tokens_out=int(sum(r.n_tokens for r in recs)),
-            decode_impl="fused", sim_s=sim, decode_steps=decode_steps,
+            sim_s=sim, decode_steps=decode_steps,
             prefill_calls=prefill_calls, n_requests=len(recs),
             n_cancelled=sum(1 for r in recs if r.cancelled),
             mean_occupancy=sched.mean_occupancy,
@@ -802,7 +729,6 @@ class EngineEnvironment(BaseEnvironment):
         lat = float(np.mean([r.latency_s for r in st.records]))
         metadata = {"backend": "engine", "scheduler": "continuous",
                     "prefill_s": st.prefill_s, "decode_s": st.decode_s,
-                    "decode_impl": st.decode_impl,
                     "tokens_per_s": st.tokens_per_s,
                     "goodput_rps": st.goodput_rps,
                     "n_requests": st.n_requests,
@@ -851,7 +777,6 @@ class EngineEnvironment(BaseEnvironment):
         p = self.board.power(level, util) if m is None else m.avg_watts
         metadata = {"backend": "engine", "prefill_s": st.prefill_s,
                     "decode_s": st.decode_s,
-                    "decode_impl": st.decode_impl,
                     "tokens_per_s": st.tokens_per_s}
         if m is not None:
             metadata.update(sensor=m.sensor_name,

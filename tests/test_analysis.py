@@ -5,7 +5,7 @@ Stage 1 (lint) is tested against golden fixtures in
 marker must be flagged with exactly those rule ids, and nothing else in
 the fixture may be flagged.  Stage 2 (jaxpr audit) is tested by
 sabotage: a planted ``jax.debug.callback``, a planted ``.item()`` in the
-fused decode body, and an engine whose decode jit keys on the start
+slot-pool decode body, and an engine whose decode jit keys on the start
 position must each fail the gate.
 """
 
@@ -179,12 +179,12 @@ def test_audit_item_in_fused_decode_fails_gate(tmp_path):
         budgets_path=str(tmp_path / "budgets.json"),
         families=[], engine_factory=sabotaged,
         include_retrace=False)
-    assert any(f.rule == "A106" and f.entry == "engine/fused_decode"
+    assert any(f.rule == "A106" and f.entry == "engine/continuous_decode"
                for f in findings)
 
 
 def test_retrace_audit_flags_value_keyed_decode_cache():
-    """An engine whose fused-decode jit keys on the start position value
+    """An engine whose decode-loop jit keys on the start position value
     (static_argnums instead of a traced scalar) must fail A105 when the
     prompt bucket changes."""
     from repro.analysis.jaxpr_audit import (default_engine_factory,
@@ -192,25 +192,25 @@ def test_retrace_audit_flags_value_keyed_decode_cache():
 
     def sabotaged():
         eng = default_engine_factory()
-        fn = eng._fused_decode_fn
+        fn = eng._fused_continuous_fn
         wrapped = jax.jit(
-            lambda p, tok, cache, mask, start_pos, steps: fn(
+            lambda p, tok, cache, mask, start_pos, *rest: fn(
                 p, tok, cache, mask, jnp.asarray(start_pos, jnp.int32),
-                steps),
-            static_argnums=(4, 5))
+                *rest),
+            static_argnums=(4, 10))
 
         class Shim:
-            def __call__(self, p, tok, cache, mask, start_pos, steps):
-                return wrapped(p, tok, cache, mask, int(start_pos), steps)
+            def __call__(self, p, tok, cache, mask, start_pos, *rest):
+                return wrapped(p, tok, cache, mask, int(start_pos), *rest)
 
             def _cache_size(self):
                 return wrapped._cache_size()
 
-        eng._fused_decode = Shim()
+        eng._fused_continuous = Shim()
         return eng
 
     findings = retrace_audit(engine_factory=sabotaged)
-    assert any(f.rule == "A105" and "decode_fused" in f.message
+    assert any(f.rule == "A105" and "decode_continuous" in f.message
                for f in findings)
 
 

@@ -1,10 +1,10 @@
-"""Engine hot path: fused fori_loop decode vs the per-token reference,
+"""Engine hot path: the slot-pool decode loop vs a per-token reference,
 left-pad masking, prompt bucketing, input validation, and the retrace /
 cache-reuse bounds a controller sweep relies on — plus the continuous-
 batching differential harness: slot-level admission must be invisible in
-the token streams (bit-identical to static batching when no slot churn
-happens, and per-request streams independent of co-resident slots when
-it does)."""
+the token streams (bit-identical to the per-token reference when no slot
+churn happens, and per-request streams independent of co-resident slots
+when it does)."""
 
 import dataclasses
 
@@ -40,19 +40,43 @@ def _prompts(cfg, lengths, seed=0):
             for n in lengths]
 
 
+def _stepwise_reference(eng, prompts, max_new_tokens):
+    """Greedy tokens [B, max_new_tokens] from a per-token Python loop: one
+    jitted prefill of the left-padded batch, then one jitted
+    `decode_step` per token under the decode mask `generate` builds
+    (prompt pads invalid, every decode-written position valid)."""
+    bundle, b = eng.bundle, len(prompts)
+    toks, mask, plen = eng._pad_batch(prompts)
+    logits, cache = jax.jit(bundle.prefill)(
+        eng.params, jnp.asarray(toks), bundle.init_cache(b, eng.max_seq_len),
+        attn_mask=jnp.asarray(mask))
+    dec_mask = np.ones((b, eng.max_seq_len), bool)
+    dec_mask[:, :plen] = mask
+    dec_mask = jnp.asarray(dec_mask)
+    step = jax.jit(bundle.decode_step)
+    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    out = np.zeros((b, max_new_tokens), np.int32)
+    for i in range(max_new_tokens):
+        out[:, i] = np.asarray(tok)
+        logits, cache = step(eng.params, tok, cache,
+                             jnp.asarray(plen + i, jnp.int32),
+                             attn_mask=dec_mask)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return out
+
+
 @pytest.mark.parametrize("name", FAMILIES)
 def test_fused_bit_identical_to_loop(name):
-    """The fused fori_loop decode must produce exactly the greedy tokens
-    of the per-token reference loop on every model family."""
-    eng, cfg = _engine(name, decode_impl="fused")
-    ref = InferenceEngine(eng.bundle, eng.params, max_batch=8,
-                          max_seq_len=48, decode_impl="loop")
+    """`generate` (the slot-pool decode loop) must produce exactly the
+    greedy tokens of the per-token reference loop on every model
+    family."""
+    eng, cfg = _engine(name)
     prompts = _prompts(cfg, [5, 9, 7])
-    out_f, st_f = eng.generate(prompts, max_new_tokens=8)
-    out_l, st_l = ref.generate(prompts, max_new_tokens=8)
-    np.testing.assert_array_equal(out_f, out_l)
-    assert st_f.decode_impl == "fused" and st_l.decode_impl == "loop"
-    assert out_f.shape == (3, 8)
+    out, st = eng.generate(prompts, max_new_tokens=8)
+    np.testing.assert_array_equal(out, _stepwise_reference(eng, prompts, 8))
+    assert out.shape == (3, 8) and out.dtype == np.int32
+    assert st.tokens_out == 24 and st.decode_steps == 8
+    assert st.prefill_calls == 1 and st.chunks == 1
 
 
 def test_generate_validation_errors():
@@ -69,9 +93,6 @@ def test_generate_validation_errors():
     with pytest.raises(ValueError, match="max_seq_len"):
         # bucketed to 16, 16 + 40 > 48
         eng.generate(good, max_new_tokens=40)
-    with pytest.raises(ValueError, match="decode_impl"):
-        InferenceEngine(eng.bundle, eng.params, max_batch=2,
-                        max_seq_len=48, decode_impl="eager")
     with pytest.raises(ValueError, match="prompt_bucket"):
         InferenceEngine(eng.bundle, eng.params, max_batch=2,
                         max_seq_len=48, prompt_bucket=0)
@@ -145,7 +166,7 @@ def test_prompt_bucketing_preserves_tokens():
 
 def test_sweep_compiles_once_per_shape():
     """A 10-round controller-style sweep over batch arms must compile the
-    prefill and fused decode once per (batch, bucket) on first touch and
+    prefill and the decode loop once per (batch, bucket) on first touch and
     never again: `compile_counts` stays flat and distinct batch arms hit
     distinct cache-pool entries."""
     env = make_env("engine/smollm-360m", seed=0, prompt_len=16,
@@ -156,8 +177,8 @@ def test_sweep_compiles_once_per_shape():
     baseline = dict(env.engine.compile_counts)
     assert baseline["cache_pool"] == len(batches)
     assert baseline["prefill"] == len(batches)
-    assert baseline["decode_fused"] == len(batches)
-    assert baseline["decode_loop"] == 0
+    assert baseline["decode_continuous"] == len(batches)
+    assert baseline["admit"] == 0
     for rnd in range(1, 10):
         env.pull({"freq_mhz": 930.75, "batch": batches[rnd % 2]}, rnd)
         assert env.engine.compile_counts == baseline, \
@@ -168,7 +189,6 @@ def test_engine_env_reports_throughput():
     env = make_env("engine/smollm-360m", seed=0, prompt_len=16,
                    max_new_tokens=8, max_batch=8, max_seq_len=64)
     obs = env.pull({"freq_mhz": 930.75, "batch": 4}, 0)
-    assert obs.metadata["decode_impl"] == "fused"
     assert obs.metadata["tokens_per_s"] > 0
 
 
@@ -179,13 +199,14 @@ def test_engine_env_reports_throughput():
 def test_continuous_identity_matches_static(name):
     """Differential identity: with every request present at t=0, equal
     budgets and no EOS, continuous scheduling performs exactly the static
-    fused schedule (one seed prefill, no admission, no early exit) — the
-    per-request token streams must be bit-identical to `generate` on
-    every model family.  chunk=3 additionally crosses jit boundaries
-    mid-decode (3+3+2 steps), which must not perturb the carry."""
+    schedule (one seed prefill, no admission, no early exit) — the
+    per-request token streams must be bit-identical to the per-token
+    reference on every model family.  chunk=3 additionally crosses jit
+    boundaries mid-decode (3+3+2 steps), which must not perturb the
+    carry."""
     eng, cfg = _engine(name)
     prompts = _prompts(cfg, [5, 9, 7])
-    out_s, _ = eng.generate(prompts, max_new_tokens=8)
+    out_s = _stepwise_reference(eng, prompts, 8)
     for chunk in (8, 3):
         reqs = [EngineRequest(rid=i, prompt=p, max_new_tokens=8)
                 for i, p in enumerate(prompts)]
@@ -292,6 +313,18 @@ def test_continuous_rejects_encdec():
                         max_new_tokens=4)
     with pytest.raises(ValueError, match="encdec"):
         eng.generate_continuous([req])
+
+
+def test_generate_rejects_encdec():
+    """`generate` runs the slot-pool loop, so the encdec family is refused
+    up front with the loop's error, not deep inside the model."""
+    cfg = C.get_smoke("seamless-m4t-large-v2")
+    b = bundle_for(cfg)
+    params = b.init_params(jax.random.PRNGKey(0))
+    eng = InferenceEngine(b, params, max_batch=2, max_seq_len=48)
+    with pytest.raises(ValueError, match="does not serve the encdec"):
+        eng.generate([np.ones(4, np.int32), np.ones(6, np.int32)],
+                     max_new_tokens=4)
 
 
 def test_engine_env_continuous_reports_goodput():
